@@ -21,6 +21,7 @@ reported alongside.
 from __future__ import annotations
 
 import functools
+import os
 import pathlib
 import socket
 import subprocess
@@ -75,6 +76,33 @@ def provenance() -> dict:
     out = dict(_static_provenance())
     out["ts_utc"] = time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime())
     return out
+
+
+HOST_CHILD_REFUSAL = (
+    "runs on forced host devices in a child process, which a process "
+    "holding an accelerator must not start; on TPUs run "
+    "`python chip_smoke.py --chips 4` for the multi-chip paths")
+
+
+def holds_accelerator() -> bool:
+    """Whether this process runs jax on an accelerator (and so holds it)."""
+    return jax.default_backend() != "cpu"
+
+
+def host_device_env(devices: int) -> dict:
+    """Environment for a child process that runs on ``devices`` forced
+    host (CPU) devices — jax pins its device count at first init, so a
+    virtual mesh needs a fresh interpreter.  The child is pinned to the
+    CPU backend, and a process that holds an accelerator may not start
+    one at all."""
+    if holds_accelerator():
+        raise RuntimeError(f"this benchmark {HOST_CHILD_REFUSAL}")
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["PYTHONPATH"] = str(_REPO_ROOT / "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    return env
 
 
 def load_datasets(scale: float = BENCH_SCALE, include_nell: bool = False):

@@ -28,10 +28,11 @@ Output: ``name,us_per_call,derived`` CSV like the other sections, plus
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 import textwrap
+
+from .common import host_device_env
 
 DEVICES = 8
 
@@ -131,11 +132,7 @@ _CHILD = """
 
 
 def run(devices: int = DEVICES) -> str:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env = host_device_env(devices)
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(_CHILD)],
                          capture_output=True, text=True, env=env,
                          timeout=1200)

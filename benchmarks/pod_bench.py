@@ -34,10 +34,11 @@ correctness/overhead smokes, not scaling claims.
 from __future__ import annotations
 
 import json
-import os
 import subprocess
 import sys
 import textwrap
+
+from .common import host_device_env
 
 DEVICES = 8
 
@@ -156,11 +157,7 @@ _CHILD = """
 
 
 def run(devices: int = DEVICES, smoke: bool = False) -> str:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env = host_device_env(devices)
     code = textwrap.dedent(_CHILD).format(devices=devices, smoke=smoke)
     out = subprocess.run([sys.executable, "-c", code],
                          capture_output=True, text=True, env=env,

@@ -18,6 +18,7 @@ import pathlib
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import clock as obs_clock
 from repro.obs import history as obs_history
 
@@ -160,6 +161,7 @@ def _check_pod_rows(rows) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     argv = sys.argv[1:]
     flags = {a for a in argv if a.startswith("--")}
     want = {a for a in argv if not a.startswith("--")}
@@ -240,6 +242,12 @@ def main() -> None:
 
     for name, title, fn in sections:
         print(f"\n===== {title} =====")
+        if name in ("dist", "pod"):
+            from . import common
+            if common.holds_accelerator():
+                print(f"===== skipped: {name} {common.HOST_CHILD_REFUSAL} "
+                      f"=====")
+                continue
         t0 = obs_clock.now()
         rows = fn()
         wall = obs_clock.now() - t0

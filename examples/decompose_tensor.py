@@ -9,6 +9,9 @@ import sys
 import time
 
 from repro.core import Scheme, cpd_als, frostt_like, make_plan
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 name = sys.argv[1] if len(sys.argv) > 1 and not sys.argv[1].startswith("-") \
     else "chicago"

@@ -14,10 +14,13 @@ import jax
 from repro import optim
 from repro.configs import get_config, reduce_config
 from repro.data import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.models import factorized_embed as fe
 from repro.runtime import Trainer
+
+enable_compile_cache()
 
 base = dataclasses.replace(
     reduce_config(get_config("qwen1.5-4b")),

@@ -5,6 +5,9 @@
 import numpy as np
 
 from repro.core import cpd_als, low_rank_sparse, make_plan, mttkrp, random_sparse
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. a synthetic 3-mode sparse tensor (power-law index skew, like FROSTT)
 t = random_sparse((500, 120, 40), 20_000, seed=0, distribution="powerlaw")

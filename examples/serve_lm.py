@@ -11,7 +11,10 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduce_config
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_model
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--quant-kv", action="store_true")

@@ -11,9 +11,12 @@ import dataclasses
 from repro import optim
 from repro.configs import get_config
 from repro.data import TokenPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import get_model
 from repro.runtime import Trainer
+
+enable_compile_cache()
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=300)

@@ -66,7 +66,7 @@ from jax import lax
 from jax.scipy import linalg as jsla
 
 from ..kernels import ref as kref
-from ..kernels.mttkrp_pallas import mttkrp_pallas
+from ..kernels.mttkrp_pallas import mttkrp_pallas, resolve_interpret
 from ..obs import clock as obs_clock
 from ..obs import trace as obs_trace
 from ..obs.ledger import LEDGER as _LEDGER
@@ -406,6 +406,20 @@ class SweepContext:
 # ---------------------------------------------------------------------------
 
 
+def _f32_matmuls(sweep):
+    """Trace ``sweep`` with f32 matmul precision.  A TPU's default f32
+    matmul is a single bf16 pass, which would round the grams, the
+    normal-equations solve and the fit to bf16 and drift the fits away
+    from the float64 host loop; on the CPU the setting changes nothing."""
+
+    @functools.wraps(sweep)
+    def run(*args):
+        with jax.default_matmul_precision("float32"):
+            return sweep(*args)
+
+    return run
+
+
 @functools.lru_cache(maxsize=None)
 def build_sweep_fn(backend: str, nmodes: int, rank: int,
                    shapes: tuple[int, ...],
@@ -476,8 +490,9 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
             weighted_fit=_build_weighted_fit(nmodes, rank, axis),
             hadamard=functools.partial(_hadamard_grams, rank=rank),
         )
-        return spec.build_sweep(ctx)
+        return _f32_matmuls(spec.build_sweep(ctx))
 
+    @_f32_matmuls
     def sweep(state, mode_data_all, fit_data):
         factors, grams, weights = list(state[0]), list(state[1]), state[2]
         for d in range(nmodes):
@@ -730,7 +745,7 @@ def cpd_als_fused(
     seed: int = 0,
     backend: str = "segment",
     check_every: int = 1,
-    interpret: bool = True,
+    interpret: bool | None = None,
     donate: bool | None = None,
     solver: str = "auto",
     method: str = "cp",
@@ -787,6 +802,7 @@ def cpd_als_fused(
         # Buffer donation is a no-op (with a warning) on CPU.
         donate = jax.default_backend() != "cpu"
     solver = resolve_solver(solver)
+    interpret = resolve_interpret(interpret)
 
     structural = spec is not None and spec.valued_mode_data
     if plan is None and backend == "coo":
@@ -820,11 +836,11 @@ def cpd_als_fused(
     shapes = tuple(int(s) for s in tensor.shape)
     n_blocks, rem = divmod(n_iters, check_every)
     sweep_k = _build_sweep_block(
-        backend, N, rank, shapes, pallas_meta, bool(interpret), bool(donate),
+        backend, N, rank, shapes, pallas_meta, interpret, bool(donate),
         solver, check_every, method,
     ) if n_blocks else None
     sweep_rem = _build_sweep_block(
-        backend, N, rank, shapes, pallas_meta, bool(interpret), bool(donate),
+        backend, N, rank, shapes, pallas_meta, interpret, bool(donate),
         solver, rem, method,
     ) if rem else None
 
@@ -866,7 +882,7 @@ def cpd_als_fused(
     mttkrp_seconds = 0.0
     if profile_mttkrp and windows_run and not structural:
         mttkrp_seconds = _profile_mttkrp_replay(
-            backend, N, rank, shapes, pallas_meta, bool(interpret),
+            backend, N, rank, shapes, pallas_meta, interpret,
             state[0], mode_data_all, windows_run)
 
     return CPDResult(

@@ -242,9 +242,10 @@ def frostt_like(name: str, *, scale: float = 1.0, seed: int = 0) -> SparseTensor
     nnz_s = max(int(nnz * scale), 128)
     # Small mode dims are kept EXACT — they decide which load-balancing
     # scheme the adaptive rule picks (the paper's central structure);
-    # only large dims shrink, and never below what nnz can populate.
+    # only large dims shrink, and never below what nnz can populate.  At
+    # scale=1 every dim is the Table III one.
     shape_s = tuple(
-        I if I <= 2048 else min(max(2048, int(I * scale * 4)), nnz_s)
+        I if I <= 2048 else min(I, max(2048, int(I * scale * 4)), nnz_s)
         for I in shape
     )
     return random_sparse(shape_s, nnz_s, seed=seed, distribution="powerlaw")

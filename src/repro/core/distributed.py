@@ -49,11 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs import clock as obs_clock
 from ..obs import trace as obs_trace
@@ -69,6 +65,18 @@ from .layout import build_mode_layout
 from .load_balance import Scheme
 
 AXIS = "sm"
+
+
+def shard_map(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-axis check off.  The ALS sweeps
+    combine per-device partials with explicit ``psum``/``all_gather`` and
+    carry replicated state through ``lax.scan``/``lax.while_loop``; with
+    the check on, such a carry comes back typed as device-varying (it
+    mixes in per-device shard arrays) and the loop refuses the mismatch.
+    The distributed sweeps here and the pod engine
+    (``serve.batched_engine``) go through this one wrapper."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass
@@ -153,8 +161,7 @@ def _dist_mttkrp(idx, rows, vals, factors, num_rows, mesh_):
         return lax.psum(out, AXIS)
 
     in_specs = (P(AXIS), P(AXIS), P(AXIS)) + tuple(P() for _ in factors)
-    fn = shard_map(body, mesh=mesh_, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = shard_map(body, mesh=mesh_, in_specs=in_specs, out_specs=P())
     return fn(idx, rows, vals, *factors)
 
 
@@ -209,7 +216,7 @@ def _build_dist_sweep_block(mesh_: Mesh, nmodes: int, rank: int,
     ``build_sweep_fn`` (gather = the scheme-1 payload fix)."""
     if mode_widths is None:
         mode_widths = (4,) * nmodes
-    sweep = build_sweep_fn("segment", nmodes, rank, shapes, None, True,
+    sweep = build_sweep_fn("segment", nmodes, rank, shapes, None, None,
                            solver, axis=AXIS, method=method,
                            collectives=collectives)
     offs = [0]
@@ -234,7 +241,6 @@ def _build_dist_sweep_block(mesh_: Mesh, nmodes: int, rank: int,
         body, mesh=mesh_,
         in_specs=(P(),) + tuple(P(AXIS) for _ in range(n_sharded)),
         out_specs=(P(), P()),
-        check_rep=False,
     )
     return _LEDGER.register(
         "dist_block",
@@ -288,26 +294,24 @@ def _collect_dist_data(plan: DistributedPlan,
     (``+ (own_rows, gather_map)`` for gather-collective modes),
     ``(idx, rows, row_perm, idx_full, vals, ew)`` for the valued/weighted
     masked contract (see ``methods.masked``).  Also returns the per-mode
-    widths for the flat-arg slicing."""
+    widths for the flat-arg slicing.  Each array is placed straight onto
+    the mesh, one leading-axis slice per device, so no device ever holds
+    another device's shard."""
+    shard = NamedSharding(plan.mesh, P(AXIS))
     flat = []
     widths = []
     for d, m in enumerate(plan.modes):
         if m.idx_full is not None:
-            flat += [jnp.asarray(m.idx), jnp.asarray(m.rows),
-                     jnp.asarray(m.row_perm), jnp.asarray(m.idx_full),
-                     jnp.asarray(m.vals), jnp.asarray(m.ew)]
-            widths.append(6)
+            arrs = [m.idx, m.rows, m.row_perm, m.idx_full, m.vals, m.ew]
         elif collectives is not None and collectives[d] == "gather":
-            flat += [jnp.asarray(m.idx), jnp.asarray(m.rows),
-                     jnp.asarray(m.vals), jnp.asarray(m.row_perm),
-                     jnp.asarray(m.own_rows), jnp.asarray(m.gather_map)]
-            widths.append(6)
+            arrs = [m.idx, m.rows, m.vals, m.row_perm, m.own_rows,
+                    m.gather_map]
         else:
-            flat += [jnp.asarray(m.idx), jnp.asarray(m.rows),
-                     jnp.asarray(m.vals), jnp.asarray(m.row_perm)]
-            widths.append(4)
-    flat += [jnp.asarray(a) for a in plan.fit_shards]
-    return flat, tuple(widths)
+            arrs = [m.idx, m.rows, m.vals, m.row_perm]
+        flat += arrs
+        widths.append(len(arrs))
+    flat += list(plan.fit_shards)
+    return [jax.device_put(a, shard) for a in flat], tuple(widths)
 
 
 def cpd_als_distributed(

@@ -3,7 +3,7 @@
 Backends:
   'segment' — vectorized jnp: fused gather–Hadamard–segment_sum on the
               sorted layout.  Production CPU path and kernel oracle.
-  'pallas'  — the TPU Pallas kernel (interpret=True on CPU).
+  'pallas'  — the TPU Pallas kernel (interpreted on the CPU backend only).
   'coo'     — unsorted elementwise formulation (naive baseline; materializes
               the (nnz, R) intermediate the paper eliminates).
 
@@ -151,6 +151,20 @@ def _segment_backend(input_indices, rows, values, factors, row_perm, num_rows):
     return jnp.zeros_like(out_rel).at[row_perm].set(out_rel)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "num_rows", "num_row_blocks", "block_rows", "tile", "rank_block",
+    "interpret"))
+def _pallas_backend(rb_of, first, idxp, valsp, lrowsp, factors, row_perm,
+                    num_rows, num_row_blocks, block_rows, tile, rank_block,
+                    interpret):
+    out_rel = mttkrp_pallas(
+        rb_of, first, idxp, valsp, lrowsp, list(factors),
+        num_row_blocks=num_row_blocks, block_rows=block_rows, tile=tile,
+        rank_block=rank_block, interpret=interpret,
+    )[:num_rows]
+    return jnp.zeros_like(out_rel).at[row_perm].set(out_rel)
+
+
 @functools.partial(jax.jit, static_argnames=("mode", "num_rows"))
 def _coo_backend(indices, values, factors, mode, num_rows):
     return kref.mttkrp_coo(indices, values, list(factors), mode, num_rows)
@@ -162,7 +176,7 @@ def mttkrp(
     mode: int,
     *,
     backend: str = "segment",
-    interpret: bool = True,
+    interpret: bool | None = None,
     rank_block: int | None = None,
 ) -> jnp.ndarray:
     """MTTKRP along ``mode``: returns (I_mode, R) f32 in original row order."""
@@ -180,14 +194,13 @@ def mttkrp(
         if rank_block is None:
             rank = int(in_factors[0].shape[1])
             rank_block = plan.mode_plan(mode, rank).rank_block
-        rb_of, first, idxp, valsp, lrowsp = plan.device_packed(mode)
-        out_rel = mttkrp_pallas(
-            rb_of, first, idxp, valsp, lrowsp, in_factors,
+        return _pallas_backend(
+            *plan.device_packed(mode), tuple(in_factors),
+            jnp.asarray(lay.row_perm), num_rows=packed.num_rows,
             num_row_blocks=packed.num_row_blocks,
             block_rows=packed.block_rows, tile=packed.tile,
             rank_block=rank_block, interpret=interpret,
-        )[: packed.num_rows]
-        return jnp.zeros_like(out_rel).at[jnp.asarray(lay.row_perm)].set(out_rel)
+        )
     if backend == "coo":
         indices, values = plan.device_coo()
         return _coo_backend(

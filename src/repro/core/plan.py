@@ -337,7 +337,7 @@ def _mode_plan(stats, mode: int, rank: int, factor_rows: int, nnz_cap: int,
         tile = tile if tile is not None else t
     num_inputs = len(stats.input_modes())
     rblk = kops.auto_rank_block(rank, block_rows, tile, factor_rows,
-                                num_inputs) or rank
+                                num_inputs, mode=mode)
     nb = max(1, -(-stats.num_rows // block_rows))
     if isinstance(stats, _ObservedModeStats):
         # Observed density: the cost chooser decides the segment
@@ -446,9 +446,9 @@ class PodPlan:
     key stabilizer) and then to a mesh multiple, so ``shard_map`` slices
     the stacked arrays exactly — the padding slots are filled by
     repeating the last request (exact under vmap: independent lanes whose
-    results are discarded)."""
+    results are discarded).  The sizing is the same for every bucket;
+    the bucket's kernel tiling stays with ``plan_bucket``."""
 
-    bucket: PartitionPlan
     num_devices: int
     batch_quantum: int = 1
 
@@ -462,19 +462,6 @@ class PodPlan:
         n = max(1, int(self.num_devices))
         tot = -(-tot // n) * n
         return tot, tot // n
-
-
-def plan_pod(shape: tuple[int, ...], nnz_cap: int, rank: int,
-             kappa: int = 1, *, num_devices: int, batch_quantum: int = 1,
-             density: tuple | None = None) -> PodPlan:
-    """Pod plan for a (shape, nnz_cap) bucket class: the bucket's static
-    ``plan_bucket`` plus the batch-axis sharding arithmetic."""
-    return PodPlan(
-        bucket=plan_bucket(tuple(int(s) for s in shape), int(nnz_cap),
-                           int(rank), int(kappa), density=density),
-        num_devices=int(num_devices),
-        batch_quantum=int(batch_quantum),
-    )
 
 
 def pod_lane_order(nnz: list[int], num_devices: int) -> list[int]:

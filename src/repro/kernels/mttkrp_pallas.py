@@ -20,9 +20,15 @@ TPU-native re-think of the paper's R x P thread-block kernel (§IV-B):
     (bit-identical to the single-block kernel) and removes the hard VMEM
     rank ceiling the single-block version had.
   * Factor-row gathers and the final scatter-reduce both become one-hot
-    matmuls on the MXU when the index space is small (`onehot`), else
-    vector gathers (`take`).  The Hadamard accumulator ``l`` (paper's
-    l(r)) lives in VREGs/VMEM for its whole life.
+    matmuls on the MXU.  A factor taller than ``GATHER_CHUNK`` rows is
+    zero-padded to a chunk multiple and gathered chunk by chunk in a
+    ``fori_loop``: each nonzero's one-hot row has a single 1 across all
+    chunks, so the sum over chunks is the exact gathered row.  Mosaic has
+    no vector gather from a VMEM ref, so this is the form that compiles
+    for any factor whose block fits VMEM.  Matmuls run at f32 contract
+    precision, which makes the one-hot gather exact.  The Hadamard
+    accumulator ``l`` (paper's l(r)) lives in VREGs/VMEM for its whole
+    life.
 
 Block layout (VMEM, per grid step):
   idx_ref   : (W, T)   int32   input-mode indices (lane dim = T)
@@ -48,6 +54,53 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# Factor rows gathered per one-hot matmul: a lane multiple, so the
+# (tile, GATHER_CHUNK) one-hot operand is MXU-aligned and stays small in
+# VMEM however tall the factor is.
+GATHER_CHUNK = 512
+
+# Scoped VMEM the kernel may use.  A v5e TensorCore has 128 MiB of VMEM;
+# the planner (kernels.ops) sizes blocks against a budget below this.
+VMEM_LIMIT_BYTES = 64 * 2**20
+
+# The one-hot gather is exact only at f32 contract precision, so the
+# kernel asks for it.  On a v5e, Mosaic gives f32 operands that precision
+# at DEFAULT too: both settings produce bit-identical MTTKRPs there.
+_F32 = lax.Precision.HIGHEST
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` means: interpret the kernel only on the CPU
+    backend, compile it everywhere else.  Every front door defaults to
+    None and resolves here, so a TPU run never interprets silently."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
+def _gather_rows(fac, idx_col, tile: int):
+    """``fac[idx]`` as one-hot matmuls: (tile, RB) f32.  ``idx_col`` is
+    the (tile, 1) int32 index column; ``fac`` a VMEM ref whose row count
+    is at most ``GATHER_CHUNK`` or a multiple of it."""
+    rows, width = fac.shape
+    if rows <= GATHER_CHUNK:
+        iota = lax.broadcasted_iota(jnp.int32, (tile, rows), 1)
+        onehot = (idx_col == iota).astype(jnp.float32)
+        return jnp.dot(onehot, fac[...].astype(jnp.float32),
+                       precision=_F32, preferred_element_type=jnp.float32)
+    iota = lax.broadcasted_iota(jnp.int32, (tile, GATHER_CHUNK), 1)
+
+    def chunk(c, acc):
+        start = pl.multiple_of(c * GATHER_CHUNK, GATHER_CHUNK)
+        onehot = (idx_col == iota + start).astype(jnp.float32)
+        part = fac[pl.ds(start, GATHER_CHUNK), :].astype(jnp.float32)
+        return acc + jnp.dot(onehot, part, precision=_F32,
+                             preferred_element_type=jnp.float32)
+
+    return lax.fori_loop(0, rows // GATHER_CHUNK, chunk,
+                         jnp.zeros((tile, width), jnp.float32))
+
+
 def _kernel(
     rb_of_ref,
     first_ref,
@@ -58,7 +111,6 @@ def _kernel(
     num_inputs: int,
     block_rows: int,
     tile: int,
-    gather_onehot_max: int,
 ):
     factor_refs = refs[:num_inputs]
     out_ref = refs[num_inputs]
@@ -71,28 +123,15 @@ def _kernel(
     vals = val_ref[0, :].astype(jnp.float32)          # (T,)
     prod = vals[:, None]                              # (T, 1) -> bcast to (T, RB)
     for w in range(num_inputs):
-        fac = factor_refs[w]
-        idx_w = idx_ref[w, :]                         # (T,)
-        I_w = fac.shape[0]
-        if I_w <= gather_onehot_max:
-            # Gather as a one-hot matmul: MXU-friendly, no random access.
-            iota = lax.broadcasted_iota(jnp.int32, (tile, I_w), 1)
-            onehot = (idx_w[:, None] == iota).astype(jnp.float32)
-            fw = jnp.dot(
-                onehot, fac[...].astype(jnp.float32),
-                preferred_element_type=jnp.float32,
-            )
-        else:
-            # Vector gather from the VMEM-resident factor block.
-            fw = jnp.take(fac[...], idx_w, axis=0).astype(jnp.float32)
-        prod = prod * fw                              # Hadamard accumulate (VREG)
+        idx_col = idx_ref[w, :][:, None]              # (T, 1)
+        prod = prod * _gather_rows(factor_refs[w], idx_col, tile)
 
     # Segmented reduce into the row block: one-hot^T @ prod on the MXU.
     lrow = lrow_ref[0, :]                             # (T,)
     iota_r = lax.broadcasted_iota(jnp.int32, (tile, block_rows), 1)
     scatter = (lrow[:, None] == iota_r).astype(jnp.float32)   # (T, BR)
     out_ref[...] += jnp.dot(
-        scatter.T, prod, preferred_element_type=jnp.float32
+        scatter.T, prod, precision=_F32, preferred_element_type=jnp.float32
     )
 
 
@@ -108,15 +147,19 @@ def mttkrp_pallas(
     block_rows: int,
     tile: int,
     rank_block: int | None = None,
-    interpret: bool = True,
-    gather_onehot_max: int = 2048,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Run the segmented MTTKRP kernel. Returns (num_row_blocks*block_rows, R) f32.
 
     ``rank_block`` tiles the rank dimension: each rank block re-streams the
     slabs with only that block of factor/output columns in VMEM.  ``None``
-    (or >= R) keeps the whole rank resident — the original behavior.
+    (or >= R) keeps the whole rank resident — the original behavior.  A
+    compiled kernel needs ``rank_block`` equal to R or a multiple of 128
+    (the planner in ``kernels.ops`` only offers those).
+    ``interpret=None`` interprets on the CPU backend only (see
+    ``resolve_interpret``).
     """
+    interpret = resolve_interpret(interpret)
     W = idx_packed.shape[0]
     if W != len(factors):
         raise ValueError(f"{W} index rows but {len(factors)} input factors")
@@ -130,12 +173,18 @@ def mttkrp_pallas(
         raise ValueError(f"rank_block must be >= 1, got {rank_block}")
     num_rank_blocks = -(-R // rank_block)
     R_pad = num_rank_blocks * rank_block
-    if R_pad != R:
-        # Zero-pad the rank dimension so it divides evenly; padded columns
-        # compute zeros and are sliced off below.
-        factors = [
-            jnp.pad(f, ((0, 0), (0, R_pad - R))) for f in factors
-        ]
+    # Zero-pad the rank dimension so it divides evenly (padded columns
+    # compute zeros and are sliced off below), and tall factors' rows to
+    # a GATHER_CHUNK multiple (padded rows are never selected).
+    padded = []
+    for f in factors:
+        rows = f.shape[0]
+        rows_pad = (rows if rows <= GATHER_CHUNK
+                    else -(-rows // GATHER_CHUNK) * GATHER_CHUNK)
+        if rows_pad != rows or R_pad != R:
+            f = jnp.pad(f, ((0, rows_pad - rows), (0, R_pad - R)))
+        padded.append(f)
+    factors = padded
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -158,7 +207,6 @@ def mttkrp_pallas(
         num_inputs=W,
         block_rows=block_rows,
         tile=tile,
-        gather_onehot_max=gather_onehot_max,
     )
     out_shape = jax.ShapeDtypeStruct(
         (num_row_blocks * block_rows, R_pad), jnp.float32
@@ -167,6 +215,8 @@ def mttkrp_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(rb_of, first, idx_packed, vals_packed, lrows_packed, *factors)
     if R_pad != R:

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from ..obs import trace as obs_trace
 from . import ref as ref_mod
-from .mttkrp_pallas import mttkrp_pallas
+from .mttkrp_pallas import GATHER_CHUNK, VMEM_LIMIT_BYTES, mttkrp_pallas
 
 DEFAULT_TILE = 256
 DEFAULT_BLOCK_ROWS = 128
@@ -280,34 +280,78 @@ def pack_layout(layout, *, block_rows: int = DEFAULT_BLOCK_ROWS,
 # ``num_rows`` / ``nnz`` / ``nmodes`` / ``row_ptr``.
 
 _MXU_DIM = 128
-_VMEM_BYTES = 16 * 2**20
+_LANES = 128
+_SUBLANES = 8
+# Planning budget: three quarters of the kernel's scoped VMEM limit, the
+# rest left to Mosaic's own scratch.
+_VMEM_BYTES = VMEM_LIMIT_BYTES * 3 // 4
 _STEP_OVERHEAD_SLOTS = 192   # pipeline bubble per grid step, in slot units
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-int(x) // m) * m
+
+
 def tile_candidates():
-    return [(br, t) for br in (8, 32, 128, 256) for t in (64, 128, 256, 512)]
+    """(block_rows, tile) pairs the compiled kernel accepts: a slab block
+    is (W, tile) / (1, tile), so ``tile`` must be a lane multiple; the
+    output block is (block_rows, rank_block), so ``block_rows`` a sublane
+    multiple."""
+    return [(br, t) for br in (8, 32, 128, 256) for t in (128, 256, 512)]
+
+
+def kernel_vmem_bytes(block_rows: int, tile: int, rank_block: int,
+                      factor_rows: int, num_inputs: int) -> int:
+    """VMEM the kernel needs for one (block_rows, tile, rank_block)
+    choice: every pipelined block twice (Pallas double-buffers inputs and
+    output), padded to the (8, 128) f32 tiling — a rank block narrower
+    than 128 lanes still occupies 128 — plus the in-kernel working set
+    (the one-hot gather and scatter operands and the Hadamard product).
+    Each factor is padded by less than ``GATHER_CHUNK`` rows."""
+    lanes = _round_up(rank_block, _LANES)
+    slabs = (_round_up(num_inputs, _SUBLANES) + 2 * _SUBLANES) * tile * 4
+    factors = (factor_rows + GATHER_CHUNK * num_inputs) * lanes * 4
+    out = block_rows * lanes * 4
+    work = tile * (GATHER_CHUNK + block_rows + 3 * lanes) * 4
+    return 2 * (slabs + factors + out) + work
+
+
+def _fit_rank_block(rank: int, block_rows: int, tile: int, factor_rows: int,
+                    num_inputs: int, vmem_budget: int) -> int:
+    """Widest rank block the compiled kernel accepts (R itself, or a
+    multiple of 128 below R) that fits ``vmem_budget``; 0 if none does."""
+    cands = [rank] + list(range(_round_up(rank, _LANES) - _LANES, 0,
+                                -_LANES))
+    for rb in cands:
+        if kernel_vmem_bytes(block_rows, tile, rb, factor_rows,
+                             num_inputs) <= vmem_budget:
+            return rb
+    return 0
+
+
+def _no_fit_error(mode, rank: int, factor_rows: int, vmem_budget: int,
+                  need: int) -> ValueError:
+    return ValueError(
+        f"pallas MTTKRP cannot plan mode {mode}: its input factors have "
+        f"{factor_rows} rows in all, and at rank {rank} the narrowest "
+        f"rank block needs {need} bytes of VMEM against a budget of "
+        f"{vmem_budget} bytes; use backend='segment' for this tensor")
 
 
 def auto_rank_block(rank: int, block_rows: int, tile: int, factor_rows: int,
-                    num_inputs: int, *, vmem_budget: int = _VMEM_BYTES) -> int:
-    """Largest rank block whose VMEM working set (slabs + one output tile +
-    one column block of every input factor) fits ``vmem_budget``.
-
-    Returns ``rank`` when the whole rank fits (no tiling), else the widest
-    feasible block, preferring lane-aligned multiples of 128.  Returns 0
-    when even a single column cannot fit (slab arrays alone overflow).
-    """
-    fixed = (num_inputs + 2) * tile * 4
-    per_col = (block_rows + factor_rows) * 4
-    avail = vmem_budget - fixed
-    if avail < per_col:
-        return 0
-    max_cols = int(avail // per_col)
-    if max_cols >= rank:
-        return rank
-    if max_cols >= _MXU_DIM:
-        return (max_cols // _MXU_DIM) * _MXU_DIM
-    return max_cols
+                    num_inputs: int, *, vmem_budget: int = _VMEM_BYTES,
+                    mode: int | None = None) -> int:
+    """Rank block for the kernel: ``rank`` when the whole rank fits the
+    VMEM budget (no tiling), else the widest multiple of 128 that does.
+    Raises ``ValueError`` naming ``mode``, the factor rows and the budget
+    when not even one block fits."""
+    rb = _fit_rank_block(rank, block_rows, tile, factor_rows, num_inputs,
+                         vmem_budget)
+    if rb == 0:
+        need = kernel_vmem_bytes(block_rows, tile, min(rank, _LANES),
+                                 factor_rows, num_inputs)
+        raise _no_fit_error(mode, rank, factor_rows, vmem_budget, need)
+    return rb
 
 
 def estimate_pack_cost(layout, block_rows: int, tile: int, rank: int,
@@ -319,10 +363,10 @@ def estimate_pack_cost(layout, block_rows: int, tile: int, rank: int,
     mxu_factor = cost of the (tile x block_rows) scatter matmul relative to
                  a lane-saturated tile (block_rows < 128 wastes MXU columns;
                  block_rows > 128 adds proportional work)
-    vmem       = slabs + one (row block, rank block) output tile + one rank
-                 block of the resident factors; when the full rank does not
-                 fit, the rank dimension is tiled (grid (R_blocks, G)) and
-                 every rank block re-streams the slabs, multiplying cost.
+    vmem       = ``kernel_vmem_bytes`` of the chosen rank block; when the
+                 full rank does not fit, the rank dimension is tiled in
+                 128-column blocks (grid (R_blocks, G)) and every rank
+                 block re-streams the slabs, multiplying cost.
     """
     nb = max(1, -(-layout.num_rows // block_rows))
     row_ptr = layout.row_ptr
@@ -335,11 +379,11 @@ def estimate_pack_cost(layout, block_rows: int, tile: int, rank: int,
     pad = 1.0 - layout.nnz / max(slots, 1)
     mxu_factor = max(block_rows, _MXU_DIM) / _MXU_DIM
     W = layout.nmodes - 1
-    rank_block = auto_rank_block(rank, block_rows, tile, factor_rows, W,
-                                 vmem_budget=vmem_budget)
+    rank_block = _fit_rank_block(rank, block_rows, tile, factor_rows, W,
+                                 vmem_budget)
     num_rank_blocks = -(-rank // rank_block) if rank_block else 0
-    vmem = ((W + 2) * tile * 4
-            + (block_rows + factor_rows) * min(rank_block, rank) * 4)
+    vmem = kernel_vmem_bytes(block_rows, tile, rank_block or rank,
+                             factor_rows, W)
     cost = (slots * mxu_factor + G * _STEP_OVERHEAD_SLOTS) * max(
         num_rank_blocks, 1)
     return {"block_rows": block_rows, "tile": tile, "grid": G,
@@ -355,7 +399,8 @@ def auto_tiles(layout, rank: int = 32, factor_rows: int | None = None):
     VMEM budget.  The default (128, 256) is good for dense-ish modes; skewed
     or tiny modes prefer smaller row blocks (less slab padding).  Candidates
     whose factors only fit via rank tiling are costed with the re-streaming
-    multiplier rather than rejected."""
+    multiplier rather than rejected.  Raises ``ValueError`` naming the mode
+    when no candidate fits the budget."""
     if factor_rows is None:
         factor_rows = sum(layout.shape[w] for w in layout.input_modes())
     best = None
@@ -365,9 +410,12 @@ def auto_tiles(layout, rank: int = 32, factor_rows: int | None = None):
             continue
         if best is None or c["cost"] < best["cost"]:
             best = c
-    if best is None:   # slab arrays alone overflow VMEM: nothing feasible
-        best = estimate_pack_cost(layout, DEFAULT_BLOCK_ROWS, DEFAULT_TILE,
-                                  rank, factor_rows)
+    if best is None:
+        br, t = min(tile_candidates())
+        need = kernel_vmem_bytes(br, t, min(rank, _LANES), factor_rows,
+                                 layout.nmodes - 1)
+        raise _no_fit_error(layout.mode, rank, factor_rows, _VMEM_BYTES,
+                            need)
     return best["block_rows"], best["tile"]
 
 
@@ -376,8 +424,7 @@ def mttkrp_packed(
     factors: Sequence[jnp.ndarray],
     *,
     rank_block: int | None = None,
-    interpret: bool = True,
-    gather_onehot_max: int = 2048,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Run the Pallas kernel on a packed layout.  ``factors`` are the input
     factor matrices in ``packed.input_modes`` order.  Returns the relabeled
@@ -390,13 +437,14 @@ def mttkrp_packed(
 
     ``rank_block=None`` auto-sizes the rank tile from the VMEM model: the
     full rank stays resident when it fits, else the widest feasible column
-    block is used and the kernel makes one slab pass per rank block."""
+    block is used and the kernel makes one slab pass per rank block; a
+    factor set that fits no block raises (see ``auto_rank_block``)."""
     if rank_block is None:
         rank = int(factors[0].shape[1])
         factor_rows = sum(int(f.shape[0]) for f in factors)
         rank_block = auto_rank_block(
-            rank, packed.block_rows, packed.tile, factor_rows, len(factors)
-        ) or rank
+            rank, packed.block_rows, packed.tile, factor_rows, len(factors),
+            mode=packed.mode)
     out = mttkrp_pallas(
         jnp.asarray(packed.rb_of),
         jnp.asarray(packed.first),
@@ -409,7 +457,6 @@ def mttkrp_packed(
         tile=packed.tile,
         rank_block=rank_block,
         interpret=interpret,
-        gather_onehot_max=gather_onehot_max,
     )
     return out[: packed.num_rows]
 

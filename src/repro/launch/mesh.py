@@ -13,13 +13,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types when the jax version has them
-    (jax.sharding.AxisType landed after 0.4.x)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:      # older jax: no explicit-sharding axis types
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types on every axis."""
     return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

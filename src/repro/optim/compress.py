@@ -14,10 +14,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -71,7 +67,7 @@ def cross_pod_mean(grads, err_state, mesh, *, compress: bool = True,
         es = jax.tree.map(lambda t: t[1], outs, is_leaf=lambda x: isinstance(x, tuple))
         return gs, es
 
-    fn = shard_map(
+    fn = jax.shard_map(
         tree_body, mesh=mesh,
         in_specs=(every, every), out_specs=(every, every),
     )

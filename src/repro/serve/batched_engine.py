@@ -37,7 +37,7 @@ same padded nnz ⇒ identical array shapes regardless of which
 load-balancing scheme each tensor picked), ``coo`` (no host-side layout
 preprocessing at all), and ``pallas``: each bucket-mate's layout is
 packed to the bucket's static ``core.plan`` slab cap, so the slab arrays
-share one shape and the kernel vmaps (interpret mode on CPU).  The
+share one shape and the kernel vmaps (interpreted on the CPU backend).  The
 pallas path packs the UNPADDED tensors (slab-cap padding replaces nnz
 padding), which keeps the batched result bit-identical to the
 per-request sequential pallas engine under the same plan (the masked
@@ -62,17 +62,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax keeps shard_map under experimental
-    from jax.experimental.shard_map import shard_map
 
 from ..core import als_device
 from ..core import plan as plan_mod
 from ..core.coo import SparseTensor
 from ..core.cpd import CPDResult
+from ..core.distributed import shard_map
 from ..core.layout import build_all_mode_layouts
 from ..kernels import ops as kops
+from ..kernels.mttkrp_pallas import resolve_interpret
 from ..obs import clock as obs_clock
 from ..obs import trace as obs_trace
 from ..obs.ledger import LEDGER as _LEDGER
@@ -236,7 +234,6 @@ def _build_pod_block(mesh_, backend: str, nmodes: int, rank: int,
         pod_body, mesh=mesh_,
         in_specs=(Pb, Pb, Pb, Pb, Pb),
         out_specs=(Pb, P(None, axis), P()),
-        check_rep=False,
     )
     return _LEDGER.register(
         "pod_block",
@@ -268,7 +265,7 @@ class BatchedEngine:
 
     def __init__(self, rank: int, *, kappa: int = 1,
                  backend: str = "segment", check_every: int = 4,
-                 interpret: bool = True, donate: bool | None = None,
+                 interpret: bool | None = None, donate: bool | None = None,
                  solver: str = "auto", mesh=None, batch_quantum: int = 1,
                  lane_placement: str = "balanced"):
         if backend not in _BATCH_BACKENDS:
@@ -283,7 +280,7 @@ class BatchedEngine:
         self.kappa = kappa
         self.backend = backend
         self.check_every = max(1, int(check_every))
-        self.interpret = bool(interpret)
+        self.interpret = resolve_interpret(interpret)
         if donate is None:
             donate = jax.default_backend() != "cpu"
         self.donate = bool(donate)
@@ -301,15 +298,13 @@ class BatchedEngine:
         """Mesh size of the pod path (1 when running single-device)."""
         return 1 if self.mesh is None else int(self.mesh.devices.size)
 
-    def pod_plan(self, shape: tuple[int, ...], nnz_cap: int,
-                 density: tuple | None = None) -> plan_mod.PodPlan:
-        """The pod sizing plan for a bucket class (mesh path only)."""
+    @property
+    def pod_plan(self) -> plan_mod.PodPlan:
+        """The pod sizing plan, shared by every bucket (mesh path only)."""
         if self.mesh is None:
             raise ValueError("engine has no mesh; pod_plan is undefined")
-        return plan_mod.plan_pod(
-            shape, nnz_cap, self.rank, self.kappa,
-            num_devices=self.num_devices,
-            batch_quantum=self.batch_quantum, density=density)
+        return plan_mod.PodPlan(num_devices=self.num_devices,
+                                batch_quantum=self.batch_quantum)
 
     # -- data staging -------------------------------------------------------
 
@@ -516,8 +511,7 @@ class BatchedEngine:
             # repeat the last request into the padding lanes.  Exact:
             # lanes are independent under vmap/shard_map and the padded
             # lanes' results are discarded below.
-            B, _ = self.pod_plan(shape, cap, density).dispatch_batch(
-                requested)
+            B, _ = self.pod_plan.dispatch_batch(requested)
             if B > requested:
                 tensors = repeat_pad(tensors, B)
                 seeds = repeat_pad(list(seeds), B)

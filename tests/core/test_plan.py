@@ -196,9 +196,9 @@ def test_pack_rejects_overflowing_cap():
 def test_pod_plan_dispatch_arithmetic():
     """Batch is rounded up to the quantum FIRST, then to a mesh multiple,
     and the per-device sub-batch divides exactly."""
-    from repro.core.plan import plan_pod
+    from repro.core.plan import PodPlan
 
-    pp = plan_pod((12, 13, 14), 256, 4, num_devices=8, batch_quantum=3)
+    pp = PodPlan(num_devices=8, batch_quantum=3)
     assert pp.dispatch_batch(1) == (8, 1)     # 1 -> 3 (quantum) -> 8 (mesh)
     assert pp.dispatch_batch(8) == (16, 2)    # 8 -> 9 -> 16
     assert pp.dispatch_batch(13) == (16, 2)
@@ -211,9 +211,22 @@ def test_pod_plan_dispatch_arithmetic():
         assert tot >= b and tot == per * 8
     with pytest.raises(ValueError):
         pp.dispatch_batch(0)
-    # The underlying bucket plan is the SAME cached object plan_bucket
-    # hands everyone else — pod sharding adds arithmetic, not a new plan.
-    assert pp.bucket is plan_bucket((12, 13, 14), 256, 4, 1, density=None)
+
+
+def test_pod_sizing_needs_no_kernel_tiling():
+    """Pod batch sizing plans no kernel tiling, so a segment pod engine
+    sizes a bucket whose factors exceed the kernel's VMEM budget (enron's
+    244,268-row mode); the bucket's own kernel plan is an error naming
+    the mode."""
+    from repro.launch.mesh import make_batch_mesh
+    from repro.serve import BatchedEngine
+
+    shape = (6_066, 5_699, 244_268, 1_176)
+    eng = BatchedEngine(32, backend="segment", mesh=make_batch_mesh(1),
+                        batch_quantum=4)
+    assert eng.pod_plan.dispatch_batch(5) == (8, 8)
+    with pytest.raises(ValueError, match="mode 0"):
+        eng.bucket_plan(shape, 1 << 20)
 
 
 def test_observed_density_moves_chosen_kappa():

@@ -57,15 +57,20 @@ def test_kernel_blockspec_sweep(block_rows, tile):
 
 
 def test_gather_paths_agree():
-    """One-hot MXU gather vs vector-gather path must give identical results."""
-    t = random_sparse((300, 12, 9), 500, seed=7)
+    """The single one-hot gather (factor <= GATHER_CHUNK rows) and the
+    chunked gather (taller factors, zero-padded to a chunk multiple) both
+    match the packed oracle; mode 0 takes both paths in one call."""
+    from repro.kernels.mttkrp_pallas import GATHER_CHUNK
+
+    t = random_sparse((40, 2 * GATHER_CHUNK + 77, 9), 700, seed=7)
     factors = _factors(t.shape, 8, seed=8)
-    plan = make_plan(t, kappa=2, block_rows=8, tile=32)
-    packed = plan.packed(0)
-    in_f = [factors[w] for w in plan.layouts[0].input_modes()]
-    a = np.asarray(kops.mttkrp_packed(packed, in_f, gather_onehot_max=4096))
-    b = np.asarray(kops.mttkrp_packed(packed, in_f, gather_onehot_max=0))
-    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    plan = make_plan(t, kappa=2, block_rows=8, tile=128)
+    for mode in (0, 1):
+        packed = plan.packed(mode)
+        in_f = [factors[w] for w in plan.layouts[mode].input_modes()]
+        a = np.asarray(kops.mttkrp_packed(packed, in_f))
+        b = np.asarray(kops.mttkrp_packed_ref(packed, in_f))
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 def test_packing_invariants():
@@ -116,28 +121,74 @@ def test_rank_blocked_kernel():
 
 
 def test_rank_block_forced_by_vmem_budget():
-    """auto_rank_block tiles the rank when factors overflow the budget, and
-    the auto path through mttkrp_packed stays correct."""
-    # Factors far larger than 16 MiB of f32 columns: must tile below R.
-    rb = kops.auto_rank_block(64, 128, 256, factor_rows=10**6, num_inputs=2)
-    assert 1 <= rb < 64
-    assert -(-64 // rb) >= 2
+    """auto_rank_block tiles the rank in 128-column blocks when the whole
+    rank overflows the budget, and the auto path through mttkrp_packed
+    stays correct."""
     # Whole rank fits -> no tiling.
     assert kops.auto_rank_block(64, 128, 256, 200, 2) == 64
+    # Rank 256 over 40k factor rows: only a 128-column block fits.
+    assert kops.auto_rank_block(256, 128, 256, 40_000, 2) == 128
+    # A rank below 128 lanes is never split: R itself or an error.
+    budget = kops.kernel_vmem_bytes(16, 128, 32, 48, 2)
+    assert kops.auto_rank_block(32, 16, 128, 48, 2,
+                                vmem_budget=budget) == 32
+    with pytest.raises(ValueError, match="mode 0"):
+        kops.auto_rank_block(32, 16, 128, 48, 2, vmem_budget=budget - 1,
+                             mode=0)
     # estimate_pack_cost reports the tiling and scales cost by the passes.
     t = random_sparse((64, 32, 16), 800, seed=23)
-    plan = make_plan(t, kappa=2, block_rows=16, tile=64)
+    plan = make_plan(t, kappa=2, block_rows=16, tile=128)
     lay = plan.layouts[0]
-    small = kops.estimate_pack_cost(lay, 16, 64, 32, 48,
-                                    vmem_budget=4096)
-    big = kops.estimate_pack_cost(lay, 16, 64, 32, 48)
+    small = kops.estimate_pack_cost(
+        lay, 16, 128, 256, 48,
+        vmem_budget=kops.kernel_vmem_bytes(16, 128, 128, 48, 2))
+    big = kops.estimate_pack_cost(lay, 16, 128, 256, 48)
     assert small["num_rank_blocks"] > big["num_rank_blocks"] == 1
     assert small["vmem_ok"] and small["cost"] > big["cost"]
-    # End-to-end through the mttkrp wrapper with an explicit small block.
+    # End-to-end through the mttkrp wrapper with an explicit small block
+    # (interpret mode accepts any block width).
     factors = _factors(t.shape, 32, seed=24)
     a = np.asarray(mttkrp(plan, factors, 0, backend="pallas", rank_block=8))
     b = np.asarray(mttkrp(plan, factors, 0, backend="segment"))
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_planner_only_offers_compilable_blocks():
+    """Tiles are lane multiples, row blocks sublane multiples, and a rank
+    block is R itself or a multiple of 128."""
+    for br, tile in kops.tile_candidates():
+        assert tile % 128 == 0 and br % 8 == 0
+    for rank in (8, 32, 100, 128, 200, 384):
+        for rows in (100, 10_000, 40_000, 80_000):
+            try:
+                rb = kops.auto_rank_block(rank, 128, 256, rows, 3)
+            except ValueError:
+                continue
+            assert rb == rank or rb % 128 == 0
+
+
+def test_planner_raises_when_factor_cannot_fit_vmem():
+    """A mode whose input factors overflow VMEM at every tiling is an
+    error naming the mode, its factor rows and the budget, not a silent
+    default tiling (enron's 244,268-row mode at rank 32)."""
+    from repro.core.plan import plan_bucket
+
+    shape = (6_066, 5_699, 244_268, 1_176)
+    with pytest.raises(ValueError) as e:
+        plan_bucket(shape, 1 << 20, 32)
+    msg = str(e.value)
+    assert "mode 0" in msg and "251143 rows" in msg
+    assert str(kops._VMEM_BYTES) in msg
+
+
+def test_interpret_resolves_from_platform():
+    """interpret=None interprets on the CPU backend (the test platform)
+    and an explicit value passes through."""
+    from repro.kernels.mttkrp_pallas import resolve_interpret
+
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True
 
 
 def test_auto_tiles_valid_and_correct():
@@ -147,7 +198,7 @@ def test_auto_tiles_valid_and_correct():
     for mode in range(3):
         lay = plan0.layouts[mode]
         br, tile = kops.auto_tiles(lay, rank=8)
-        assert br in (8, 32, 128, 256) and tile in (64, 128, 256, 512)
+        assert br in (8, 32, 128, 256) and tile in (128, 256, 512)
         plan = make_plan(t, kappa=4, block_rows=br, tile=tile)
         factors = _factors(t.shape, 8, seed=12)
         pal = np.asarray(mttkrp(plan, factors, mode, backend="pallas"))

@@ -45,3 +45,13 @@ def test_frostt_like_structure(name):
     # no duplicate coordinates
     dedup = t.deduplicate()
     assert dedup.nnz == t.nnz
+
+
+@pytest.mark.parametrize("scale,want", [(1.0, (5000, 7)), (0.5, (3000, 7)),
+                                        (0.1, (600, 7))])
+def test_frostt_like_large_dims_never_exceed_table(monkeypatch, scale, want):
+    """A large dim shrinks with scale and never grows past its Table III
+    size: scale=1 is the published shape."""
+    monkeypatch.setitem(FROSTT_SHAPES, "tiny", ((5000, 7), 6000))
+    t = frostt_like("tiny", scale=scale, seed=0)
+    assert t.shape == want

@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device, in %."""
+
+
+def read(r):
+    if r.trace is None or r.trace.window_s <= 0.0:
+        return None
+    return 100.0 * r.trace.idle_share

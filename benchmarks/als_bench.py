@@ -11,13 +11,10 @@ Measures, per Table-3 dataset generator (CI-scaled):
     iterations (+1 final materialization);
   * the partition plan each timed config ran under (per-mode block_rows /
     tile / rank_block / slab cap, via ``core.plan``), so a perf regression
-    is attributable to a planning change rather than guessed at;
-  * a SEPARABLE ``mttkrp_seconds`` for the fused engine: the sweep stages
-    are ``jax.named_scope``-annotated for real profiler traces, and
-    ``profile_mttkrp=True`` times a jitted MTTKRP-only replay of the same
-    check windows (kernel cost is independent of factor values, so the
-    replay is faithful) — reported as ``mttkrp_s_per_iter`` and as the
-    fraction of fused time spent in the bottleneck kernel.
+    is attributable to a planning change rather than guessed at.
+
+The fused engine's MTTKRP time is in a profiler trace, under the sweep's
+``mttkrp/mode<d>`` named scopes.
 
 Output: ``name,us_per_call,derived`` CSV like the other sections.
 """
@@ -61,13 +58,6 @@ def bench_one(name, tensor, *, rank=RANK, iters=ITERS,
                           check_every=check_every)
     fused_s = time.perf_counter() - t0
 
-    # Separate the bottleneck kernel from solve time: one more (warm) run
-    # with the MTTKRP-only window replay enabled — the timed region above
-    # stays replay-free.
-    prof = cpd_als_fused(tensor, rank, plan=plan, n_iters=iters, tol=-1.0,
-                         check_every=check_every, profile_mttkrp=True)
-    mttkrp_s = prof.mttkrp_seconds
-
     # The sync-count probe (acceptance): <= 1 per check_every iters + final.
     budget = -(-iters // check_every) + 1
     assert fused.host_syncs <= budget, (fused.host_syncs, budget)
@@ -83,8 +73,6 @@ def bench_one(name, tensor, *, rank=RANK, iters=ITERS,
         "speedup": host_s / max(fused_s, 1e-12),
         "host_syncs_per_iter": host.host_syncs / iters,
         "fused_syncs_per_iter": fused.host_syncs / iters,
-        "mttkrp_s_per_iter": mttkrp_s / iters,
-        "mttkrp_frac": mttkrp_s / max(fused_s, 1e-12),
         "plan": pplan.describe(),
     }
 
@@ -102,9 +90,7 @@ def main():
               f"syncs_per_iter={r['host_syncs_per_iter']:.1f}")
         print(f"als/{r['dataset']}/fused,{r['fused_s_per_iter']*1e6:.0f},"
               f"syncs_per_iter={r['fused_syncs_per_iter']:.2f};"
-              f"speedup={r['speedup']:.2f}x;"
-              f"mttkrp_us_per_iter={r['mttkrp_s_per_iter']*1e6:.0f};"
-              f"mttkrp_frac={r['mttkrp_frac']:.2f};plan={r['plan']}")
+              f"speedup={r['speedup']:.2f}x;plan={r['plan']}")
     gmean = float(np.exp(np.mean([np.log(r["speedup"]) for r in rows])))
     print(f"als/geomean-speedup,0,{gmean:.2f}x")
     return rows

@@ -6,7 +6,13 @@ Public API:
   ModeLayout, build_mode_layout, build_all_mode_layouts       (layout)
   MTTKRPPlan, make_plan, mttkrp                               (mttkrp)
   cpd_als, CPDResult                                          (cpd)
+
+Importing this package puts ``repro.obs.trace`` spans on the JAX
+profiler's clock (``obs.trace.bridge_profiler``).
 """
+import jax as _jax
+
+from ..obs import trace as _obs_trace
 from .als_device import cpd_als_fused, state_from_factors, sweep_cache_stats
 from .coo import SparseTensor, frostt_like, low_rank_sparse, random_sparse
 from .cpd import CPDResult, cpd_als
@@ -31,3 +37,6 @@ __all__ = [
     "choose_scheme", "choose_scheme_cost_based", "partition_mode", "scheme_cost",
     "MTTKRPPlan", "make_plan", "mttkrp", "mttkrp_dense_ref",
 ]
+
+_obs_trace.bridge_profiler(_jax.profiler.TraceAnnotation.is_enabled,
+                           _jax.profiler.TraceAnnotation)

@@ -43,10 +43,14 @@ so every method rides the same executable cache, the same ``lax.scan``
 window structure, and the same vmapped batched engine.
 
 Every stage of the sweep is wrapped in ``jax.named_scope`` ("mttkrp",
-"solve", "fit", …) so a profiler trace separates kernel time from solve
-time; ``profile_mttkrp=True`` additionally times a jitted MTTKRP-only
-replay of the same windows so ``CPDResult.mttkrp_seconds`` is populated
-even without a trace viewer.
+"solve", "fit", …), and each mode's MTTKRP in ``mode<d>`` below it, so a
+profiler trace separates kernel time from solve time and one mode's
+layout from another's (``.../mttkrp/mode0/scatter-add``).  The host side
+of a fit is in ``obs.trace`` spans, which reach the same profiler trace:
+``als.prepare`` (state, mode data and fit data uploads), one
+``als.window`` per dispatch with ``als.dispatch`` and ``als.fetch``
+(the fit sync) inside, and ``als.readback``.  ``obs.ledger`` counts each
+fit's dispatches, uploaded bytes and preparation time.
 
 ``core.cpd.cpd_als`` delegates here by default (``engine="fused"``); the
 original host loop survives as ``engine="host"`` for benchmarking.
@@ -104,6 +108,18 @@ def resolve_solver(solver: str) -> str:
 # ---------------------------------------------------------------------------
 
 
+def _mode_scope(mttkrp_fn):
+    """Run ``mttkrp_fn(d, ...)`` under ``jax.named_scope(f"mode{d}")``, so
+    that the device trace names each mode's operations."""
+
+    @functools.wraps(mttkrp_fn)
+    def run(d, *args):
+        with jax.named_scope(f"mode{d}"):
+            return mttkrp_fn(d, *args)
+
+    return run
+
+
 def _build_one_mttkrp(backend: str, nmodes: int, shapes: tuple[int, ...],
                       pallas_meta: tuple | None, interpret: bool,
                       axis: str | None,
@@ -126,6 +142,7 @@ def _build_one_mttkrp(backend: str, nmodes: int, shapes: tuple[int, ...],
     in_modes = [tuple(w for w in range(nmodes) if w != d)
                 for d in range(nmodes)]
 
+    @_mode_scope
     def one_mttkrp(d, mode_data, factors):
         """(I_d, R) f32 in ORIGINAL row order, entirely on device."""
         if backend == "segment":
@@ -208,6 +225,7 @@ def _build_valued_mttkrp(backend: str, nmodes: int, shapes: tuple[int, ...],
                 "the distributed valued MTTKRP runs on the segment backend "
                 f"(shard_map path), got {backend!r}")
 
+        @_mode_scope
         def mttkrp_valued_dist(d, mode_data, factors, vals):
             idx, rows, row_perm = mode_data
             out = kref.mttkrp_sorted_segments(
@@ -218,6 +236,7 @@ def _build_valued_mttkrp(backend: str, nmodes: int, shapes: tuple[int, ...],
 
         return mttkrp_valued_dist
 
+    @_mode_scope
     def mttkrp_valued(d, mode_data, factors, vals):
         if backend == "segment":
             idx, rows, row_perm, perm = mode_data
@@ -550,37 +569,6 @@ def _build_sweep_block(backend: str, nmodes: int, rank: int,
         fn)
 
 
-@functools.lru_cache(maxsize=None)
-def _build_mttkrp_block(backend: str, nmodes: int, rank: int,
-                        shapes: tuple[int, ...],
-                        pallas_meta: tuple | None,
-                        interpret: bool, block: int):
-    """Jitted MTTKRP-only replay of one check window: ``block`` sweeps of
-    all N mode MTTKRPs with NO solve/normalize/fit.  Timing this against
-    the full sweep block separates ``mttkrp_seconds`` from solve time
-    (kernel cost does not depend on factor values, so replaying with the
-    final factors is faithful).  The scalar reduction keeps XLA from
-    eliding the kernels."""
-    one_mttkrp = _build_one_mttkrp(backend, nmodes, shapes, pallas_meta,
-                                   interpret, None)
-
-    def run(factors, mode_data_all):
-        def body(s, _):
-            for d in range(nmodes):
-                with jax.named_scope("mttkrp"):
-                    M = one_mttkrp(d, mode_data_all[d], list(factors))
-                s = s + jnp.sum(jnp.abs(M))
-            return s, None
-
-        s, _ = lax.scan(body, jnp.float32(0.0), xs=None, length=block)
-        return s
-
-    return _LEDGER.register(
-        "mttkrp_block",
-        (backend, nmodes, rank, shapes, "block", block),
-        jax.jit(run))
-
-
 def sweep_cache_stats():
     """(hits, misses, currsize) of the compiled sweep-block cache — the
     probe for 'repeated same-shape decompositions pay zero retrace'.
@@ -604,8 +592,8 @@ def sweep_trace_stats():
     blocks only (traces=None).
 
     This is now a view over ``repro.obs.ledger.LEDGER`` (which also
-    covers the MTTKRP-replay, batched, and distributed executables —
-    query those kinds there); the old module-global registry is gone.
+    covers the batched, pod and distributed executables — query those
+    kinds there); the old module-global registry is gone.
     """
     s = _LEDGER.stats("sweep_block")
     return {"blocks": s["blocks"], "traces": s["traces"]}
@@ -734,75 +722,40 @@ def _host_state_to_device(state):
 # ---------------------------------------------------------------------------
 
 
-def cpd_als_fused(
-    tensor: SparseTensor,
-    rank: int,
-    *,
-    plan: MTTKRPPlan | None = None,
-    kappa: int = 1,
-    n_iters: int = 25,
-    tol: float = 1e-5,
-    seed: int = 0,
-    backend: str = "segment",
-    check_every: int = 1,
-    interpret: bool | None = None,
-    donate: bool | None = None,
-    solver: str = "auto",
-    method: str = "cp",
-    init_state: tuple | None = None,
-    weights: np.ndarray | None = None,
-    profile_mttkrp: bool = False,
-    verbose: bool = False,
-) -> CPDResult:
-    """Device-resident CPD-ALS.  Same initialization and update order as the
-    host-loop ``cpd_als`` (identical seed ⇒ matching trajectories up to f32
-    vs f64 solver precision), but every ``check_every``-iteration window
-    runs as one compiled ``lax.scan`` dispatch and the host syncs only at
-    window boundaries.
+def uploaded_nbytes(tree, kept=()) -> int:
+    """Bytes of the distinct device arrays in ``tree`` that are not among
+    ``kept`` (uploaded by an earlier call and cached)."""
+    skip = {id(x) for x in jax.tree_util.tree_leaves(kept)}
+    fresh = {id(x): int(x.nbytes) for x in jax.tree_util.tree_leaves(tree)
+             if id(x) not in skip}
+    return sum(fresh.values())
 
-    ``method`` selects the update rule (see ``repro.methods``); every
-    method shares this driver, the window scan, and the executable cache.
-    ``init_state`` (a host state tuple, e.g. from ``state_from_factors``)
-    warm-starts from existing factors instead of the seeded random init —
-    the streaming method's incremental-fold entry.
-    ``weights`` — per-entry observation weights in canonical COO order
-    (fractional confidences; weight 0 = treat the entry as unobserved).
-    Only weighted-fit methods ('masked') accept them; they flow into the
-    method's fit data, never into the structural layouts, so weighted and
-    unweighted requests share every packed artifact and executable.
-    ``profile_mttkrp=True`` times a jitted MTTKRP-only replay of the same
-    windows after the run so ``mttkrp_seconds`` is separable from solve
-    time (named_scope annotations additionally mark the stages for real
-    profiler traces).  The replay covers value-baked mode data only:
-    for valued-mode-data methods (masked) ``mttkrp_seconds`` stays at the
-    0.0 sentinel — use a named_scope profiler trace there.
-    """
-    t_start = obs_clock.now()
+
+@dataclasses.dataclass
+class _PreparedFit:
+    """What ``cpd_als_fused`` uploads and builds before its first
+    dispatch."""
+
+    state: tuple
+    mode_data_all: tuple
+    fit_data: tuple
+    sweep_k: Callable | None        # the check-window block
+    sweep_rem: Callable | None      # the shorter last block
+    h2d_bytes: int
+
+
+def _prepare_fit(tensor, rank, spec, *, plan, kappa, n_iters, seed,
+                 backend, check_every, interpret, donate, solver, method,
+                 init_state, weights) -> _PreparedFit:
     N = tensor.nmodes
-    check_every = max(1, int(check_every))
-    spec = _method_spec(method)
-    if weights is not None:
-        if spec is None or not spec.weighted_fit:
-            raise ValueError(
-                f"per-entry weights require a weighted-fit method "
-                f"(e.g. 'masked'), got method={method!r}")
-        weights = normalize_entry_weights(
-            validate_entry_weights(tensor.nnz, weights))
     if init_state is not None:
         state = _host_state_to_device(init_state)
     elif spec is not None and spec.init_state_host is not None:
         state = _host_state_to_device(
             spec.init_state_host(tensor.shape, rank, seed))
     else:
-        # (init_state the *parameter* shadows the module-level helper here.)
         state = _host_state_to_device(
             init_state_host(tensor.shape, rank, seed))
-
-    if donate is None:
-        # Buffer donation is a no-op (with a warning) on CPU.
-        donate = jax.default_backend() != "cpu"
-    solver = resolve_solver(solver)
-    interpret = resolve_interpret(interpret)
 
     structural = spec is not None and spec.valued_mode_data
     if plan is None and backend == "coo":
@@ -836,36 +789,119 @@ def cpd_als_fused(
     shapes = tuple(int(s) for s in tensor.shape)
     n_blocks, rem = divmod(n_iters, check_every)
     sweep_k = _build_sweep_block(
-        backend, N, rank, shapes, pallas_meta, interpret, bool(donate),
+        backend, N, rank, shapes, pallas_meta, interpret, donate,
         solver, check_every, method,
     ) if n_blocks else None
     sweep_rem = _build_sweep_block(
-        backend, N, rank, shapes, pallas_meta, interpret, bool(donate),
+        backend, N, rank, shapes, pallas_meta, interpret, donate,
         solver, rem, method,
     ) if rem else None
+    # The plan's cached arrays are counted by the plan, on first upload.
+    kept = plan.device_cache() if plan is not None else ()
+    return _PreparedFit(
+        state, mode_data_all, fit_data, sweep_k, sweep_rem,
+        uploaded_nbytes((state, mode_data_all, fit_data), kept))
 
+
+def _read_back(fits_dev, state):
+    """The fit history, factors and weights on the host: the run's final
+    materialization."""
+    fits = [float(f) for blk in jax.device_get(fits_dev) for f in blk]
+    return (fits, [np.asarray(F) for F in state[0]],
+            np.asarray(state[2], dtype=np.float64))
+
+
+def cpd_als_fused(
+    tensor: SparseTensor,
+    rank: int,
+    *,
+    plan: MTTKRPPlan | None = None,
+    kappa: int = 1,
+    n_iters: int = 25,
+    tol: float = 1e-5,
+    seed: int = 0,
+    backend: str = "segment",
+    check_every: int = 1,
+    interpret: bool | None = None,
+    donate: bool | None = None,
+    solver: str = "auto",
+    method: str = "cp",
+    init_state: tuple | None = None,
+    weights: np.ndarray | None = None,
+    verbose: bool = False,
+) -> CPDResult:
+    """Device-resident CPD-ALS.  Same initialization and update order as the
+    host-loop ``cpd_als`` (identical seed ⇒ matching trajectories up to f32
+    vs f64 solver precision), but every ``check_every``-iteration window
+    runs as one compiled ``lax.scan`` dispatch and the host syncs only at
+    window boundaries.
+
+    ``method`` selects the update rule (see ``repro.methods``); every
+    method shares this driver, the window scan, and the executable cache.
+    ``init_state`` (a host state tuple, e.g. from ``state_from_factors``)
+    warm-starts from existing factors instead of the seeded random init —
+    the streaming method's incremental-fold entry.
+    ``weights`` — per-entry observation weights in canonical COO order
+    (fractional confidences; weight 0 = treat the entry as unobserved).
+    Only weighted-fit methods ('masked') accept them; they flow into the
+    method's fit data, never into the structural layouts, so weighted and
+    unweighted requests share every packed artifact and executable.
+
+    ``CPDResult.mttkrp_seconds`` stays 0.0: the MTTKRP's device time is
+    in a profiler trace, under the ``mttkrp/mode<d>`` scopes.
+    """
+    t_start = obs_clock.now()
+    tr = obs_trace.active()
+    check_every = max(1, int(check_every))
+    spec = _method_spec(method)
+    if weights is not None:
+        if spec is None or not spec.weighted_fit:
+            raise ValueError(
+                f"per-entry weights require a weighted-fit method "
+                f"(e.g. 'masked'), got method={method!r}")
+        weights = normalize_entry_weights(
+            validate_entry_weights(tensor.nnz, weights))
+    if donate is None:
+        # Buffer donation is a no-op (with a warning) on CPU.
+        donate = jax.default_backend() != "cpu"
+    kw = dict(plan=plan, kappa=kappa, n_iters=n_iters, seed=seed,
+              backend=backend, check_every=check_every,
+              interpret=resolve_interpret(interpret), donate=bool(donate),
+              solver=resolve_solver(solver), method=method,
+              init_state=init_state, weights=weights)
+    if tr is None:
+        prep = _prepare_fit(tensor, rank, spec, **kw)
+    else:
+        with tr.span("als.prepare", cat="als") as sp:
+            prep = _prepare_fit(tensor, rank, spec, **kw)
+            sp.set(h2d_bytes=prep.h2d_bytes)
+    prepare_s = obs_clock.now() - t_start
+    state, mode_data_all, fit_data = (prep.state, prep.mode_data_all,
+                                      prep.fit_data)
+
+    n_blocks, rem = divmod(n_iters, check_every)
     fits_dev: list = []
     host_syncs = 0
     last_fit = -np.inf
     it = 0
-    windows_run: list[int] = []
-    tr = obs_trace.active()
     for b in range(n_blocks + (1 if rem else 0)):
         k = check_every if b < n_blocks else rem
-        fn = sweep_k if b < n_blocks else sweep_rem
+        fn = prep.sweep_k if b < n_blocks else prep.sweep_rem
         # Dispatch + the window-boundary fit sync, the per-window hot
-        # path: the tracing-disabled branch pays one global read and
-        # zero allocations (enforced by tests/obs/test_trace.py).
+        # path: the tracing-disabled branch reads two globals, probes the
+        # profiler and allocates nothing (enforced by
+        # tests/obs/test_trace.py).
         if tr is None:
             state, fits_blk = fn(state, mode_data_all, fit_data)
             f = float(fits_blk[-1])             # the only in-loop host sync
         else:
             with tr.span("als.window", cat="als", backend=backend,
                          method=method, window=b, sweeps=k):
-                state, fits_blk = fn(state, mode_data_all, fit_data)
-                f = float(fits_blk[-1])         # the only in-loop host sync
+                with tr.span("als.dispatch", cat="als"):
+                    state, fits_blk = fn(state, mode_data_all, fit_data)
+                with tr.span("als.fetch", cat="als"):
+                    f = float(fits_blk[-1])     # the only in-loop host sync
         fits_dev.append(fits_blk)
-        windows_run.append(k)
         it += k
         host_syncs += 1
         if verbose:
@@ -877,42 +913,22 @@ def cpd_als_fused(
     host_syncs += 1                             # final materialization
     # One batched device_get for the whole run (not a fetch per window),
     # so host_syncs honestly reflects the transfer count.
-    fits = [float(f) for blk in jax.device_get(fits_dev) for f in blk]
-
-    mttkrp_seconds = 0.0
-    if profile_mttkrp and windows_run and not structural:
-        mttkrp_seconds = _profile_mttkrp_replay(
-            backend, N, rank, shapes, pallas_meta, interpret,
-            state[0], mode_data_all, windows_run)
+    if tr is None:
+        fits, factors, lam = _read_back(fits_dev, state)
+    else:
+        with tr.span("als.readback", cat="als"):
+            fits, factors, lam = _read_back(fits_dev, state)
+    _LEDGER.count("sweep_block", dispatches=len(fits_dev),
+                  h2d_bytes=prep.h2d_bytes, prepare_s=prepare_s)
 
     return CPDResult(
-        factors=[np.asarray(F) for F in state[0]],
-        weights=np.asarray(state[2], dtype=np.float64),
+        factors=factors,
+        weights=lam,
         fits=fits,
         iters=it,
-        mttkrp_seconds=mttkrp_seconds,
+        mttkrp_seconds=0.0,
         total_seconds=obs_clock.now() - t_start,
         host_syncs=host_syncs,
         engine="fused",
         method=method,
     )
-
-
-def _profile_mttkrp_replay(backend, nmodes, rank, shapes, pallas_meta,
-                           interpret, factors, mode_data_all,
-                           windows_run) -> float:
-    """Wall time of the MTTKRP-only replay of the run's check windows
-    (compile excluded via a warm-up call per window length)."""
-    total = 0.0
-    for k in sorted(set(windows_run)):
-        fn = _build_mttkrp_block(backend, nmodes, rank, shapes, pallas_meta,
-                                 interpret, k)
-        jax.block_until_ready(fn(factors, mode_data_all))   # warm-up
-        reps = windows_run.count(k)
-        with obs_trace.span("mttkrp.replay", cat="als", backend=backend,
-                            block=k, reps=reps):
-            t0 = obs_clock.now()
-            for _ in range(reps):
-                jax.block_until_ready(fn(factors, mode_data_all))
-            total += obs_clock.now() - t0
-    return total

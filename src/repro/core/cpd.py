@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import clock as obs_clock
+from ..obs import trace as obs_trace
 from .coo import SparseTensor
 from .mttkrp import MTTKRPPlan, make_plan, mttkrp
 
@@ -111,12 +112,14 @@ def cpd_als(
     if engine == "fused" and mttkrp_fn is None:
         from .als_device import cpd_als_fused
 
-        return cpd_als_fused(
-            tensor, rank, plan=plan, kappa=kappa, n_iters=n_iters, tol=tol,
-            seed=seed, backend=backend, check_every=check_every,
-            method=method, init_state=init_state, weights=weights,
-            verbose=verbose,
-        )
+        with obs_trace.span("als.fit", cat="als", backend=backend,
+                            method=method, n_iters=n_iters, nnz=tensor.nnz):
+            return cpd_als_fused(
+                tensor, rank, plan=plan, kappa=kappa, n_iters=n_iters,
+                tol=tol, seed=seed, backend=backend,
+                check_every=check_every, method=method,
+                init_state=init_state, weights=weights, verbose=verbose,
+            )
     t_start = obs_clock.now()
     rng = np.random.default_rng(seed)
     N = tensor.nmodes

@@ -23,10 +23,24 @@ import jax.numpy as jnp
 from ..kernels import ops as kops
 from ..kernels import ref as kref
 from ..kernels.mttkrp_pallas import mttkrp_pallas
+from ..obs import trace as obs_trace
+from ..obs.ledger import LEDGER as _LEDGER
 from . import plan as plan_mod
 from .coo import SparseTensor
 from .layout import ModeLayout, build_all_mode_layouts
 from .load_balance import Scheme
+
+
+def _upload(what: str, mode: int | None, arrays) -> tuple:
+    """Upload host arrays for a plan cache, once: a ``plan.upload`` span
+    and the ledger's ``plan`` count carry the bytes."""
+    with obs_trace.span("plan.upload", cat="plan", what=what,
+                        mode=mode) as sp:
+        out = tuple(jnp.asarray(a) for a in arrays)
+        nbytes = sum(int(x.nbytes) for x in out)
+        sp.set(h2d_bytes=nbytes)
+    _LEDGER.count("plan", h2d_bytes=nbytes)
+    return out
 
 
 @dataclasses.dataclass
@@ -84,12 +98,9 @@ class MTTKRPPlan:
         if mode not in self._dev_arrays:
             lay = self.layouts[mode]
             in_modes = lay.input_modes()
-            self._dev_arrays[mode] = (
-                jnp.asarray(lay.indices[:, in_modes]),
-                jnp.asarray(lay.rows),
-                jnp.asarray(lay.values),
-                jnp.asarray(lay.row_perm),
-            )
+            self._dev_arrays[mode] = _upload("arrays", mode, (
+                lay.indices[:, in_modes], lay.rows, lay.values,
+                lay.row_perm))
         return self._dev_arrays[mode]
 
     def device_packed(self, mode: int) -> tuple:
@@ -97,24 +108,22 @@ class MTTKRPPlan:
         reused by every pallas-backend call and the fused ALS engine."""
         if mode not in self._dev_packed:
             p = self.packed(mode)
-            self._dev_packed[mode] = (
-                jnp.asarray(p.rb_of),
-                jnp.asarray(p.first),
-                jnp.asarray(p.idx_packed),
-                jnp.asarray(p.vals_packed),
-                jnp.asarray(p.lrows_packed),
-            )
+            self._dev_packed[mode] = _upload("packed", mode, (
+                p.rb_of, p.first, p.idx_packed, p.vals_packed,
+                p.lrows_packed))
         return self._dev_packed[mode]
 
     def device_coo(self) -> tuple:
         """COO indices/values as jnp device arrays (cached): the coo backend
         previously re-uploaded both from host numpy on every call."""
         if self._dev_coo is None:
-            self._dev_coo = (
-                jnp.asarray(self.tensor.indices),
-                jnp.asarray(self.tensor.values),
-            )
+            self._dev_coo = _upload("coo", None, (self.tensor.indices,
+                                                  self.tensor.values))
         return self._dev_coo
+
+    def device_cache(self) -> tuple:
+        """Every device array the plan has uploaded and keeps."""
+        return (self._dev_arrays, self._dev_packed, self._dev_coo)
 
 
 def make_plan(
@@ -128,9 +137,11 @@ def make_plan(
     tile: int = kops.DEFAULT_TILE,
     partition: plan_mod.PartitionPlan | None = None,
 ) -> MTTKRPPlan:
-    layouts = build_all_mode_layouts(
-        tensor, kappa, scheme=scheme, assignment=assignment, policy=policy
-    )
+    with obs_trace.span("plan.layouts", cat="plan", nnz=tensor.nnz,
+                        nmodes=tensor.nmodes, kappa=kappa):
+        layouts = build_all_mode_layouts(
+            tensor, kappa, scheme=scheme, assignment=assignment,
+            policy=policy)
     return MTTKRPPlan(
         tensor=tensor,
         kappa=kappa,

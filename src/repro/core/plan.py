@@ -383,29 +383,31 @@ def plan_bucket(shape: tuple[int, ...], nnz_cap: int, rank: int,
         raise ValueError(
             f"density must carry one profile per mode ({len(shape)}), got "
             f"{len(density)}")
-    modes = []
-    for d in range(len(shape)):
-        if density is not None and density[d] is not None:
-            stats = _ObservedModeStats(shape, d, nnz_cap, density[d])
-        else:
-            stats = _UniformModeStats(shape, d, nnz_cap)
-        factor_rows = sum(shape[w] for w in stats.input_modes())
-        modes.append(_mode_plan(stats, d, rank, factor_rows, nnz_cap,
-                                block_rows=block_rows, tile=tile,
-                                kappa=kappa))
-    plan = PartitionPlan(shape=shape, nnz_cap=int(nnz_cap), rank=int(rank),
-                         kappa=int(kappa), modes=tuple(modes))
-    # Inside the lru-cached body, so the event fires once per NOVEL
-    # bucket class — a trace shows exactly which plans a stream induced
-    # (with the chosen tile/rank-block/slab-cap per mode), never the
-    # cache hits.
-    obs_trace.event(
-        "plan.build", cat="plan", shape=str(shape), nnz_cap=int(nnz_cap),
-        rank=int(rank), kappa=int(kappa),
-        observed_density=density is not None, plan=plan.describe(),
-        tiles=[{"mode": m.mode, "block_rows": m.block_rows, "tile": m.tile,
-                "rank_block": m.rank_block, "slab_cap": m.slab_cap}
-               for m in plan.modes])
+    # Inside the lru-cached body, so the span opens once per NOVEL bucket
+    # class — a trace shows exactly which plans a stream induced (with
+    # the chosen tile/rank-block/slab-cap per mode) and what each cost,
+    # never the cache hits.
+    with obs_trace.span("plan.build", cat="plan", shape=str(shape),
+                        nnz_cap=int(nnz_cap), rank=int(rank),
+                        kappa=int(kappa),
+                        observed_density=density is not None) as sp:
+        modes = []
+        for d in range(len(shape)):
+            if density is not None and density[d] is not None:
+                stats = _ObservedModeStats(shape, d, nnz_cap, density[d])
+            else:
+                stats = _UniformModeStats(shape, d, nnz_cap)
+            factor_rows = sum(shape[w] for w in stats.input_modes())
+            modes.append(_mode_plan(stats, d, rank, factor_rows, nnz_cap,
+                                    block_rows=block_rows, tile=tile,
+                                    kappa=kappa))
+        plan = PartitionPlan(shape=shape, nnz_cap=int(nnz_cap),
+                             rank=int(rank), kappa=int(kappa),
+                             modes=tuple(modes))
+        sp.set(plan=plan.describe(),
+               tiles=[{"mode": m.mode, "block_rows": m.block_rows,
+                       "tile": m.tile, "rank_block": m.rank_block,
+                       "slab_cap": m.slab_cap} for m in plan.modes])
     return plan
 
 

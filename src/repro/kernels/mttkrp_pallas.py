@@ -218,6 +218,7 @@ def mttkrp_pallas(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="mttkrp_pallas",     # the kernel's name in a device trace
     )(rb_of, first, idx_packed, vals_packed, lrows_packed, *factors)
     if R_pad != R:
         out = out[:, :R]

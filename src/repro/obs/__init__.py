@@ -7,16 +7,19 @@ Three pieces, consumed by every layer of the stack:
     across the stack (plan construction, slab packing, fused-sweep
     window dispatch, batched-service flushes, distributed windows,
     streaming increments) report into the ACTIVE tracer when one is
-    installed and pay a single ``is None`` check when none is (the
-    tracing-disabled hot path adds zero allocations per dispatch —
-    enforced by test).  Traces export as JSONL or Chrome-trace JSON
+    installed and pay a single ``is None`` check and a profiler probe
+    when none is (the tracing-disabled hot path adds zero allocations
+    per dispatch — enforced by test).  While a JAX profiler session
+    captures, every span is also a profiler host event, beside the
+    device operations.  Traces export as JSONL or Chrome-trace JSON
     (viewable in ``about:tracing`` / Perfetto).
   * ``obs.ledger`` — ONE compile/retrace ledger keyed by executable
-    cache: every jitted block builder (sequential sweep, MTTKRP replay,
-    vmapped batched, distributed shard_map) registers its executables
+    cache: every jitted block builder (sequential sweep, vmapped
+    batched, pod, distributed shard_map) registers its executables
     here, and per-executable trace counts expose retraces the lru
-    hit/miss counters structurally cannot see.  Resettable and
-    test-isolated (autouse fixture in tests/conftest.py).
+    hit/miss counters structurally cannot see; per-kind counters of
+    dispatches, uploaded bytes and preparation time ride along.
+    Resettable and test-isolated (autouse fixture in tests/conftest.py).
   * ``obs.calibrate`` + ``benchmarks/obs_bench.py`` — replays the
     Table-3 generators per backend, joins predicted cost from the
     GPU-architectural model against measured span durations, and emits

@@ -98,7 +98,7 @@ def measure_mode_seconds(tensor: SparseTensor, rank: int, backend: str,
     """Warm wall seconds of ONE MTTKRP per mode (best of ``reps``),
     measured via ``calibrate.mode_mttkrp`` spans on the active tracer
     (a private fallback timer is used only when tracing is off)."""
-    tr = obs_trace.active()
+    tr = obs_trace.installed()
     N = tensor.nmodes
     shapes = tuple(int(s) for s in tensor.shape)
     plan = make_plan(tensor, 1)
@@ -215,7 +215,7 @@ def measure_compile_steady(tensor: SparseTensor, rank: int, backend: str,
     median warm window.  Requires an active tracer (the harness entry
     installs one); the retrace ledger confirms the cold window is where
     the executable's (only) trace landed."""
-    tr = obs_trace.active()
+    tr = obs_trace.installed()
     if tr is None:
         raise RuntimeError(
             "measure_compile_steady needs an active tracer "

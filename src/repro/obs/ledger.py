@@ -5,8 +5,8 @@ The stack builds jitted executables in four places, each behind its own
 executable (backend, shapes, rank, tiling, method):
 
   * ``core.als_device._build_sweep_block``   — sequential fused sweeps
-  * ``core.als_device._build_mttkrp_block``  — MTTKRP-only replay
   * ``serve.batched_engine._build_batched_block`` — vmapped service blocks
+  * ``serve.batched_engine._build_pod_block``     — pod (shard_map) blocks
   * ``core.distributed._build_dist_sweep_block``  — shard_map sweeps
 
 The lru hit/miss counters see *builder* calls, but jit re-specializes
@@ -27,6 +27,14 @@ Entries are never dropped by ``reset()``: the lru caches keep the fns
 alive for the life of the process, and keeping them lets the ledger
 distinguish "new block built" (``blocks_new``) from "existing block
 retraced" after a reset.
+
+The ledger also counts, per kind and since the last ``reset()``, what the
+program does around its executables (``count`` / ``counts``):
+``dispatches``, ``h2d_bytes`` (the bytes of the host arrays uploaded for
+a call; the plan's cached uploads count once, under the kind ``plan``)
+and ``prepare_s`` (host seconds from a call's entry to its first
+dispatch).  They are always on: a few adds per call under the lock.  The
+benchmark resets the ledger when its window opens and reads them per fit.
 """
 from __future__ import annotations
 
@@ -36,7 +44,10 @@ from typing import Any, Callable, Iterator
 
 from . import trace as _trace
 
-__all__ = ["RetraceLedger", "LEDGER"]
+__all__ = ["RetraceLedger", "LEDGER", "COUNTERS"]
+
+#: What ``RetraceLedger.count`` adds to, per kind, with its zero.
+COUNTERS = {"dispatches": 0, "h2d_bytes": 0, "prepare_s": 0.0}
 
 
 def _traces_of(fn: Any) -> int | None:
@@ -60,6 +71,8 @@ class RetraceLedger:
         self._entries: dict[tuple[str, str], dict] = {}
         # keys registered since the last reset()
         self._new: set[tuple[str, str]] = set()
+        # kind -> COUNTERS since the last reset()
+        self._counts: dict[str, dict] = {}
 
     # -- write side ---------------------------------------------------------
 
@@ -77,15 +90,27 @@ class RetraceLedger:
                      key=str(key))
         return fn
 
+    def count(self, kind: str, *, dispatches: int = 0, h2d_bytes: int = 0,
+              prepare_s: float = 0.0) -> None:
+        """Add to ``kind``'s counters (see ``COUNTERS``)."""
+        with self._lock:
+            c = self._counts.get(kind)
+            if c is None:
+                c = self._counts[kind] = dict(COUNTERS)
+            c["dispatches"] += dispatches
+            c["h2d_bytes"] += h2d_bytes
+            c["prepare_s"] += prepare_s
+
     def reset(self) -> None:
-        """Re-baseline: trace counts and the new-block set read as zero
-        after this, so per-test / per-run deltas are isolated.  Entries
-        themselves are retained (their executables stay alive in the lru
-        caches regardless)."""
+        """Re-baseline: trace counts, the new-block set and the counters
+        read as zero after this, so per-test / per-run deltas are
+        isolated.  Entries themselves are retained (their executables
+        stay alive in the lru caches regardless)."""
         with self._lock:
             for entry in self._entries.values():
                 entry["baseline"] = _traces_of(entry["fn"]) or 0
             self._new.clear()
+            self._counts.clear()
 
     @contextmanager
     def isolated(self) -> Iterator["RetraceLedger"]:
@@ -136,6 +161,17 @@ class RetraceLedger:
                 "key": key,
                 "traces": None if n is None else max(n - entry["baseline"], 0),
             })
+        return out
+
+    def counts(self, kind: str | None = None) -> dict:
+        """``COUNTERS`` since the last ``reset()`` for one kind, or summed
+        over all kinds."""
+        out = dict(COUNTERS)
+        with self._lock:
+            for k, c in self._counts.items():
+                if kind is None or k == kind:
+                    for name in out:
+                        out[name] += c[name]
         return out
 
     def kinds(self) -> list[str]:

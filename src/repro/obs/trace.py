@@ -7,14 +7,15 @@ Design constraints, in priority order:
 
        tr = trace.active()
        if tr is None:
-           ... dispatch ...          # zero obs allocations, one global read
+           ... dispatch ...          # zero obs allocations
        else:
            with tr.span("als.window", cat="als", window=k):
                ... dispatch ...
 
-   ``active()`` returns a module global — no locks, no closures, no
-   kwargs dict on the disabled branch.  A test asserts the disabled path
-   adds zero allocations per dispatch.
+   ``active()`` reads a module global and, when it is None, asks the
+   profiler probe (about 40 ns) — no locks, no closures, no kwargs dict
+   on the disabled branch.  A test asserts the disabled path adds zero
+   allocations per dispatch.
 2. **Records are plain dicts.**  One dict per finished span/event,
    appended to an in-memory list (CPython list.append is atomic under
    the GIL, so recording from scheduler/session threads needs no lock).
@@ -24,6 +25,15 @@ Design constraints, in priority order:
    ``trace_event`` JSON (``{"traceEvents": [...]}`` with ``ph: "X"``
    complete events in microseconds — drop it into ``about:tracing`` or
    https://ui.perfetto.dev).
+4. **Spans on the profiler's clock.**  While a JAX profiler session is
+   capturing, every span opened here is also a profiler ``TraceMe`` on
+   the opening thread, under its bare name with its attrs as arguments,
+   so the device trace holds the program's spans beside the device
+   operations.  With a capture open, ``active()`` returns a tracer even
+   when none is installed; its spans reach the profiler only.  This
+   module needs only the stdlib: the capture probe and the ``TraceMe``
+   factory are handed in by ``bridge_profiler``, which ``repro.core``
+   calls as it imports JAX.
 
 Every span carries wall-clock duration (``perf_counter``), process-CPU
 duration (``process_time``), thread id, and arbitrary key-value attrs
@@ -40,14 +50,35 @@ import itertools
 import json
 import os
 import threading
-from typing import IO, Any, Iterator
+from typing import IO, Any, Callable, Iterator
 
 from . import clock
 
 __all__ = [
-    "Tracer", "Span", "active", "enable", "disable", "capture", "span",
-    "event", "load_jsonl", "validate_chrome",
+    "Tracer", "Span", "active", "installed", "enable", "disable", "capture",
+    "span", "event", "bridge_profiler", "load_jsonl", "validate_chrome",
 ]
+
+
+# -- the profiler bridge -----------------------------------------------------
+
+def _not_capturing() -> bool:
+    return False
+
+
+# Whether a JAX profiler session is capturing, and the factory of its host
+# events (``TraceMe(name, **args)``): stand-ins until ``bridge_profiler``.
+_capturing: Callable[[], bool] = _not_capturing
+_traceme: Callable[..., Any] | None = None
+
+
+def bridge_profiler(capturing: Callable[[], bool],
+                    traceme: Callable[..., Any]) -> None:
+    """Put spans on the profiler's clock: ``capturing()`` says whether a
+    profiler session is capturing (called once per ``active()`` and per
+    span), ``traceme(name, **args)`` opens one of its host events."""
+    global _capturing, _traceme
+    _capturing, _traceme = capturing, traceme
 
 
 class Span:
@@ -56,7 +87,7 @@ class Span:
     tracer only when the span closes."""
 
     __slots__ = ("_tracer", "name", "cat", "args", "id", "parent", "tid",
-                 "t0", "_p0", "_stack")
+                 "t0", "_p0", "_stack", "_tm")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: dict[str, Any]):
@@ -68,20 +99,28 @@ class Span:
         self.tid = threading.get_ident()
         self._stack = tracer._thread_stack()
         self.parent = self._stack[-1].id if self._stack else None
+        self._tm = None
         self._p0 = clock.process()
         self.t0 = clock.now()
 
     def set(self, **attrs: Any) -> "Span":
         self.args.update(attrs)
+        if self._tm is not None:
+            self._tm.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "Span":
         self._stack.append(self)
+        if _capturing():
+            self._tm = _traceme(self.name, **self.args)
+            self._tm.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = clock.now()
         p1 = clock.process()
+        if self._tm is not None:
+            self._tm.__exit__(exc_type, exc, tb)
         stack = self._stack
         # Tolerate exits out of creation order (mis-nested user code):
         # remove self wherever it is rather than corrupting the stack.
@@ -134,7 +173,11 @@ class Tracer:
 
     def event(self, name: str, cat: str = "app", **attrs: Any) -> None:
         """Record an instant event (no duration), parented to the
-        innermost open span on this thread."""
+        innermost open span on this thread; while the profiler captures,
+        also a zero-length profiler event."""
+        if _capturing():
+            with _traceme(name, **attrs):
+                pass
         stack = self._thread_stack()
         self._records.append({
             "kind": "event",
@@ -225,14 +268,63 @@ def _jsonable(obj: Any) -> Any:
     return str(obj)
 
 
+class _ProfilerSpan:
+    """A span while the profiler captures and no tracer is installed: a
+    profiler host event only, nothing recorded."""
+
+    __slots__ = ("_tm",)
+
+    def __init__(self, name: str, attrs: dict[str, Any]):
+        self._tm = _traceme(name, **attrs)
+
+    def set(self, **attrs: Any) -> "_ProfilerSpan":
+        self._tm.set_metadata(**attrs)
+        return self
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._tm.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._tm.__exit__(exc_type, exc, tb)
+        return False
+
+
+class _ProfilerTracer:
+    """What ``active()`` returns while the profiler captures and no tracer
+    is installed: spans and events go to the profiler's trace only."""
+
+    def span(self, name: str, cat: str = "app",
+             **attrs: Any) -> _ProfilerSpan:
+        return _ProfilerSpan(name, attrs)
+
+    def event(self, name: str, cat: str = "app", **attrs: Any) -> None:
+        with _traceme(name, **attrs):
+            pass
+
+
+_PROFILER = _ProfilerTracer()
+
+
 # -- module-level switchboard ------------------------------------------------
 
 _ACTIVE: Tracer | None = None
 
 
-def active() -> Tracer | None:
-    """The installed tracer, or None when tracing is disabled.  Hot
-    paths read this once and branch; the None branch is allocation-free."""
+def active() -> Tracer | _ProfilerTracer | None:
+    """Where spans go: the installed tracer; else, while a profiler
+    session captures, a tracer that feeds the profiler only; else None.
+    Hot paths read this once and branch; the None branch reads two module
+    globals, calls the capture probe and allocates nothing."""
+    tr = _ACTIVE
+    if tr is None and _capturing():
+        return _PROFILER
+    return tr
+
+
+def installed() -> Tracer | None:
+    """The installed recording tracer, or None, whether or not a profiler
+    captures: for callers that read the records back."""
     return _ACTIVE
 
 
@@ -282,18 +374,20 @@ class _NullSpan:
 NULL = _NullSpan()
 
 
-def span(name: str, cat: str = "app", **attrs: Any) -> Span | _NullSpan:
+def span(name: str, cat: str = "app",
+         **attrs: Any) -> Span | _ProfilerSpan | _NullSpan:
     """Convenience for warm (non-hot) paths: a real span when tracing is
-    on, an inert one otherwise.  Hot per-dispatch sites should use the
-    ``active()`` guard instead — this form builds a kwargs dict even
-    when disabled."""
-    tr = _ACTIVE
+    on or the profiler captures, an inert one otherwise.  Hot
+    per-dispatch sites should use the ``active()`` guard instead — this
+    form builds a kwargs dict even when disabled."""
+    tr = active()
     return tr.span(name, cat, **attrs) if tr is not None else NULL
 
 
 def event(name: str, cat: str = "app", **attrs: Any) -> None:
-    """Convenience: record an instant event iff tracing is on."""
-    tr = _ACTIVE
+    """Convenience: record an instant event iff tracing is on (a
+    zero-length profiler event while the profiler captures)."""
+    tr = active()
     if tr is not None:
         tr.event(name, cat, **attrs)
 

@@ -462,8 +462,36 @@ class BatchedEngine:
         not including) the device dispatch.  Pure host work, so the
         scheduler's double-buffered flush path can run it for flush N+1
         while flush N computes on device.  Returns ``None`` for an empty
-        batch; feed the result to ``execute_prepared``."""
-        tensors = list(tensors)
+        batch; feed the result to ``execute_prepared``.  Traced as
+        ``serve.prepare``; the ledger counts its uploaded bytes and host
+        seconds under the engine's executable kind."""
+        t0 = obs_clock.now()
+        kw = dict(n_iters=n_iters, tol=tol, seeds=seeds, nnz_cap=nnz_cap,
+                  method=method, init_states=init_states, density=density,
+                  weights=weights)
+        tr = obs_trace.active()
+        if tr is None:
+            prep = self._prepare_batch(list(tensors), **kw)
+        else:
+            with tr.span("serve.prepare", cat="serve", backend=self.backend,
+                         method=method, requested=len(tensors)) as sp:
+                prep = self._prepare_batch(list(tensors), **kw)
+                if prep is not None:
+                    sp.set(shape=str(prep.shape), nnz_cap=prep.cap,
+                           B=prep.batch, h2d_bytes=prep.h2d_bytes)
+        if prep is not None:
+            _LEDGER.count(self._kind, h2d_bytes=prep.h2d_bytes,
+                          prepare_s=obs_clock.now() - t0)
+        return prep
+
+    @property
+    def _kind(self) -> str:
+        """The ledger kind of this engine's executables."""
+        return "batched_block" if self.mesh is None else "pod_block"
+
+    def _prepare_batch(self, tensors: list[SparseTensor], *, n_iters, tol,
+                       seeds, nnz_cap, method, init_states, density,
+                       weights) -> "_PreparedBatch | None":
         if not tensors:
             return None
         spec = None
@@ -577,6 +605,7 @@ class BatchedEngine:
             jnp.full((B,), -jnp.inf, dtype=jnp.float32),
             jnp.zeros((B,), dtype=jnp.int32),
         )
+        tol_dev, max_iters_dev = jnp.asarray(tol_b), jnp.asarray(n_iters_b)
         return _PreparedBatch(
             requested=requested,
             batch=B,
@@ -586,13 +615,15 @@ class BatchedEngine:
             carry=carry,
             mode_data_all=mode_data_all,
             fit_data=fit_data,
-            tol_dev=jnp.asarray(tol_b),
-            max_iters_dev=jnp.asarray(n_iters_b),
+            tol_dev=tol_dev,
+            max_iters_dev=max_iters_dev,
             max_iters=int(n_iters_b.max()),
             pallas_meta=pallas_meta,
             lane_nnz=[int(t.nnz) for t in tensors],
             lane_of=lane_of,
             t_start=t_start,
+            h2d_bytes=als_device.uploaded_nbytes(
+                (state, mode_data_all, fit_data, tol_dev, max_iters_dev)),
         )
 
     def execute_prepared(self, prep: "_PreparedBatch | None"
@@ -682,6 +713,7 @@ class BatchedEngine:
             host_syncs += 1          # the only in-loop sync: the active mask
             if not any_active:
                 break
+        _LEDGER.count(self._kind, dispatches=len(fits_dev))
 
         host_syncs += 1              # final materialization
         fits_cat = (jnp.concatenate(fits_dev, axis=0) if fits_dev
@@ -723,6 +755,7 @@ class BatchedEngine:
                 "imbalance_contiguous":
                     plan_mod.pod_imbalance(arrival, n_dev),
             }
+        _LEDGER.count(self._kind, dispatches=1)
         tr = obs_trace.active()
         if tr is None:
             carry, fits_buf, windows = fn(
@@ -802,3 +835,4 @@ class _PreparedBatch:
     # lane lane_of[i].  None when lanes are in arrival order.
     lane_of: list[int] | None
     t_start: float
+    h2d_bytes: int          # host arrays uploaded for the batch

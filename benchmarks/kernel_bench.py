@@ -63,10 +63,10 @@ def run():
         br, tl = kops.auto_tiles(plan.layouts[mode], rank=RANK)
         auto = kops.estimate_pack_cost(
             plan.layouts[mode], br, tl, RANK,
-            sum(t.shape[w] for w in in_modes))
+            [t.shape[w] for w in in_modes])
         dflt = kops.estimate_pack_cost(
             plan.layouts[mode], kops.DEFAULT_BLOCK_ROWS, kops.DEFAULT_TILE,
-            RANK, sum(t.shape[w] for w in in_modes))
+            RANK, [t.shape[w] for w in in_modes])
         # interpret-mode correctness + CPU wall (not TPU-representative)
         t0 = time.perf_counter()
         out_pal = mttkrp(plan, factors, mode, backend="pallas")

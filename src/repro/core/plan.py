@@ -328,16 +328,16 @@ def choose_segment_partition(stats, kappa_max: int) -> tuple[int, str]:
     return k, scheme
 
 
-def _mode_plan(stats, mode: int, rank: int, factor_rows: int, nnz_cap: int,
+def _mode_plan(stats, mode: int, rank: int, nnz_cap: int,
                *, block_rows: int | None, tile: int | None,
                kappa: int = 1) -> ModePlan:
+    factor_rows = [stats.shape[w] for w in stats.input_modes()]
     if block_rows is None or tile is None:
         br, t = kops.auto_tiles(stats, rank=rank, factor_rows=factor_rows)
         block_rows = block_rows if block_rows is not None else br
         tile = tile if tile is not None else t
-    num_inputs = len(stats.input_modes())
     rblk = kops.auto_rank_block(rank, block_rows, tile, factor_rows,
-                                num_inputs, mode=mode)
+                                mode=mode)
     nb = max(1, -(-stats.num_rows // block_rows))
     if isinstance(stats, _ObservedModeStats):
         # Observed density: the cost chooser decides the segment
@@ -397,8 +397,7 @@ def plan_bucket(shape: tuple[int, ...], nnz_cap: int, rank: int,
                 stats = _ObservedModeStats(shape, d, nnz_cap, density[d])
             else:
                 stats = _UniformModeStats(shape, d, nnz_cap)
-            factor_rows = sum(shape[w] for w in stats.input_modes())
-            modes.append(_mode_plan(stats, d, rank, factor_rows, nnz_cap,
+            modes.append(_mode_plan(stats, d, rank, nnz_cap,
                                     block_rows=block_rows, tile=tile,
                                     kappa=kappa))
         plan = PartitionPlan(shape=shape, nnz_cap=int(nnz_cap),
@@ -417,9 +416,8 @@ def plan_layout(layout, rank: int, *, nnz_cap: int | None = None,
     """Plan one mode from a REAL layout (exact row distribution in the
     cost model).  Used by the sequential path; ``nnz_cap`` defaults to the
     layout's own nnz, i.e. no slab padding beyond the packing minimum."""
-    factor_rows = sum(layout.shape[w] for w in layout.input_modes())
     cap = layout.nnz if nnz_cap is None else int(nnz_cap)
-    return _mode_plan(layout, layout.mode, rank, factor_rows, cap,
+    return _mode_plan(layout, layout.mode, rank, cap,
                       block_rows=block_rows, tile=tile)
 
 

@@ -19,22 +19,26 @@ TPU-native re-think of the paper's R x P thread-block kernel (§IV-B):
     VMEM.  Columns are independent in MTTKRP, so rank tiling is exact
     (bit-identical to the single-block kernel) and removes the hard VMEM
     rank ceiling the single-block version had.
-  * Factor-row gathers and the final scatter-reduce both become one-hot
-    matmuls on the MXU.  A factor taller than ``GATHER_CHUNK`` rows is
-    zero-padded to a chunk multiple and gathered chunk by chunk in a
-    ``fori_loop``: each nonzero's one-hot row has a single 1 across all
-    chunks, so the sum over chunks is the exact gathered row.  Mosaic has
-    no vector gather from a VMEM ref, so this is the form that compiles
-    for any factor whose block fits VMEM.  Matmuls run at f32 contract
-    precision, which makes the one-hot gather exact.  The Hadamard
-    accumulator ``l`` (paper's l(r)) lives in VREGs/VMEM for its whole
-    life.
+  * Factor-row gathers adapt to the factor's height.  A factor of at
+    most ``GATHER_CHUNK`` rows stays resident in VMEM and is gathered in
+    the kernel by a one-hot matmul on the MXU (the paper's
+    intermediate-free path; Mosaic has no vector gather from a VMEM ref).
+    A taller factor is gathered by XLA in HBM before the kernel, in packed
+    slot order, and its rows stream in beside the slab like the values:
+    a one-hot matmul's work grows with the factor's height, an HBM gather's
+    does not.  Both return the factor's rows exactly (matmuls run at f32
+    contract precision), and the Hadamard product multiplies the inputs in
+    the same order either way.  The final scatter-reduce is a one-hot
+    matmul too.  The Hadamard accumulator ``l`` (paper's l(r)) lives in
+    VREGs/VMEM for its whole life.
 
 Block layout (VMEM, per grid step):
   idx_ref   : (W, T)   int32   input-mode indices (lane dim = T)
   val_ref   : (1, T)   float   nonzero values
   lrow_ref  : (1, T)   int32   output row local to this row block
-  factors   : (I_w, RB) each   one rank block of each factor matrix
+  factors   : (I_w, RB)        one rank block of a factor of <= GATHER_CHUNK
+                               rows (resident), or
+              (T, RB)          its gathered rows for this slab (taller ones)
   out_ref   : (BR, RB) float32 one (row block, rank block) output tile,
                                revisited across slabs of the row block
 
@@ -53,10 +57,12 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..obs.ledger import LEDGER
 
-# Factor rows gathered per one-hot matmul: a lane multiple, so the
-# (tile, GATHER_CHUNK) one-hot operand is MXU-aligned and stays small in
-# VMEM however tall the factor is.
+
+# The tallest factor the kernel gathers by a one-hot matmul from VMEM; a
+# taller one is gathered in HBM before the kernel.  A lane multiple, so the
+# (tile, rows) one-hot operand stays within a few MXU passes.
 GATHER_CHUNK = 512
 
 # Scoped VMEM the kernel may use.  A v5e TensorCore has 128 MiB of VMEM;
@@ -79,26 +85,13 @@ def resolve_interpret(interpret: bool | None) -> bool:
 
 
 def _gather_rows(fac, idx_col, tile: int):
-    """``fac[idx]`` as one-hot matmuls: (tile, RB) f32.  ``idx_col`` is
-    the (tile, 1) int32 index column; ``fac`` a VMEM ref whose row count
-    is at most ``GATHER_CHUNK`` or a multiple of it."""
-    rows, width = fac.shape
-    if rows <= GATHER_CHUNK:
-        iota = lax.broadcasted_iota(jnp.int32, (tile, rows), 1)
-        onehot = (idx_col == iota).astype(jnp.float32)
-        return jnp.dot(onehot, fac[...].astype(jnp.float32),
-                       precision=_F32, preferred_element_type=jnp.float32)
-    iota = lax.broadcasted_iota(jnp.int32, (tile, GATHER_CHUNK), 1)
-
-    def chunk(c, acc):
-        start = pl.multiple_of(c * GATHER_CHUNK, GATHER_CHUNK)
-        onehot = (idx_col == iota + start).astype(jnp.float32)
-        part = fac[pl.ds(start, GATHER_CHUNK), :].astype(jnp.float32)
-        return acc + jnp.dot(onehot, part, precision=_F32,
-                             preferred_element_type=jnp.float32)
-
-    return lax.fori_loop(0, rows // GATHER_CHUNK, chunk,
-                         jnp.zeros((tile, width), jnp.float32))
+    """``fac[idx]`` as a one-hot matmul: (tile, RB) f32.  ``idx_col`` is
+    the (tile, 1) int32 index column; ``fac`` a VMEM ref of at most
+    ``GATHER_CHUNK`` rows."""
+    iota = lax.broadcasted_iota(jnp.int32, (tile, fac.shape[0]), 1)
+    onehot = (idx_col == iota).astype(jnp.float32)
+    return jnp.dot(onehot, fac[...].astype(jnp.float32),
+                   precision=_F32, preferred_element_type=jnp.float32)
 
 
 def _kernel(
@@ -108,10 +101,11 @@ def _kernel(
     val_ref,
     lrow_ref,
     *refs,
-    num_inputs: int,
+    gathered: tuple[bool, ...],
     block_rows: int,
     tile: int,
 ):
+    num_inputs = len(gathered)
     factor_refs = refs[:num_inputs]
     out_ref = refs[num_inputs]
     g = pl.program_id(1)          # slab index (minor grid dimension)
@@ -123,8 +117,12 @@ def _kernel(
     vals = val_ref[0, :].astype(jnp.float32)          # (T,)
     prod = vals[:, None]                              # (T, 1) -> bcast to (T, RB)
     for w in range(num_inputs):
-        idx_col = idx_ref[w, :][:, None]              # (T, 1)
-        prod = prod * _gather_rows(factor_refs[w], idx_col, tile)
+        if gathered[w]:                               # rows gathered in HBM
+            rows = factor_refs[w][...].astype(jnp.float32)
+        else:
+            idx_col = idx_ref[w, :][:, None]          # (T, 1)
+            rows = _gather_rows(factor_refs[w], idx_col, tile)
+        prod = prod * rows
 
     # Segmented reduce into the row block: one-hot^T @ prod on the MXU.
     lrow = lrow_ref[0, :]                             # (T,)
@@ -158,6 +156,13 @@ def mttkrp_pallas(
     (the planner in ``kernels.ops`` only offers those).
     ``interpret=None`` interprets on the CPU backend only (see
     ``resolve_interpret``).
+
+    An input factor of more than ``GATHER_CHUNK`` rows is gathered here,
+    in HBM, under ``jax.named_scope("hbm_gather")``: ``(G*T, R)`` rows in
+    packed slot order (padding slots carry value 0 and gather row 0), which
+    the kernel streams a ``(tile, rank_block)`` block at a time.  Each
+    trace counts how many inputs went each way under the ledger kind
+    ``pallas_gather`` (``hbm``, ``onehot``).
     """
     interpret = resolve_interpret(interpret)
     W = idx_packed.shape[0]
@@ -174,17 +179,24 @@ def mttkrp_pallas(
     num_rank_blocks = -(-R // rank_block)
     R_pad = num_rank_blocks * rank_block
     # Zero-pad the rank dimension so it divides evenly (padded columns
-    # compute zeros and are sliced off below), and tall factors' rows to
-    # a GATHER_CHUNK multiple (padded rows are never selected).
-    padded = []
-    for f in factors:
-        rows = f.shape[0]
-        rows_pad = (rows if rows <= GATHER_CHUNK
-                    else -(-rows // GATHER_CHUNK) * GATHER_CHUNK)
-        if rows_pad != rows or R_pad != R:
-            f = jnp.pad(f, ((0, rows_pad - rows), (0, R_pad - R)))
-        padded.append(f)
-    factors = padded
+    # compute zeros and are sliced off below).
+    if R_pad != R:
+        factors = [jnp.pad(f, ((0, 0), (0, R_pad - R))) for f in factors]
+    gathered = tuple(f.shape[0] > GATHER_CHUNK for f in factors)
+    LEDGER.count("pallas_gather", hbm=sum(gathered),
+                 onehot=W - sum(gathered))
+    operands, factor_specs = [], []
+    for w, f in enumerate(factors):
+        if gathered[w]:
+            with jax.named_scope("hbm_gather"):
+                operands.append(jnp.take(f, idx_packed[w], axis=0,
+                                         mode="clip"))
+            factor_specs.append(pl.BlockSpec(
+                (tile, rank_block), lambda r, g, rb, fi: (g, r)))
+        else:
+            operands.append(f)
+            factor_specs.append(pl.BlockSpec(
+                (f.shape[0], rank_block), lambda r, g, rb, fi: (0, r)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -194,17 +206,14 @@ def mttkrp_pallas(
             pl.BlockSpec((1, tile), lambda r, g, rb, fi: (0, g)),
             pl.BlockSpec((1, tile), lambda r, g, rb, fi: (0, g)),
         ]
-        + [
-            pl.BlockSpec((f.shape[0], rank_block), lambda r, g, rb, fi: (0, r))
-            for f in factors
-        ],
+        + factor_specs,
         out_specs=pl.BlockSpec(
             (block_rows, rank_block), lambda r, g, rb, fi: (rb[g], r)
         ),
     )
     kernel = functools.partial(
         _kernel,
-        num_inputs=W,
+        gathered=gathered,
         block_rows=block_rows,
         tile=tile,
     )
@@ -219,7 +228,7 @@ def mttkrp_pallas(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="mttkrp_pallas",     # the kernel's name in a device trace
-    )(rb_of, first, idx_packed, vals_packed, lrows_packed, *factors)
+    )(rb_of, first, idx_packed, vals_packed, lrows_packed, *operands)
     if R_pad != R:
         out = out[:, :R]
     return out

@@ -301,61 +301,70 @@ def tile_candidates():
 
 
 def kernel_vmem_bytes(block_rows: int, tile: int, rank_block: int,
-                      factor_rows: int, num_inputs: int) -> int:
+                      factor_rows: Sequence[int]) -> int:
     """VMEM the kernel needs for one (block_rows, tile, rank_block)
-    choice: every pipelined block twice (Pallas double-buffers inputs and
-    output), padded to the (8, 128) f32 tiling — a rank block narrower
-    than 128 lanes still occupies 128 — plus the in-kernel working set
-    (the one-hot gather and scatter operands and the Hadamard product).
-    Each factor is padded by less than ``GATHER_CHUNK`` rows."""
+    choice, given the row count of each input factor: every pipelined
+    block twice (Pallas double-buffers inputs and output), padded to the
+    (8, 128) f32 tiling — a rank block narrower than 128 lanes still
+    occupies 128 — plus the in-kernel working set (the widest one-hot
+    gather operand, the scatter operand and the Hadamard product).  A
+    factor of at most ``GATHER_CHUNK`` rows is resident whole; a taller
+    one is gathered in HBM and streams one (tile, rank_block) block."""
     lanes = _round_up(rank_block, _LANES)
-    slabs = (_round_up(num_inputs, _SUBLANES) + 2 * _SUBLANES) * tile * 4
-    factors = (factor_rows + GATHER_CHUNK * num_inputs) * lanes * 4
+    resident = [n for n in factor_rows if n <= GATHER_CHUNK]
+    streamed = len(factor_rows) - len(resident)
+    slabs = (_round_up(len(factor_rows), _SUBLANES)
+             + 2 * _SUBLANES) * tile * 4
+    factors = (sum(_round_up(n, _SUBLANES) for n in resident)
+               + streamed * tile) * lanes * 4
     out = block_rows * lanes * 4
-    work = tile * (GATHER_CHUNK + block_rows + 3 * lanes) * 4
+    onehot = _round_up(max(resident, default=0), _LANES)
+    work = tile * (onehot + block_rows + 3 * lanes) * 4
     return 2 * (slabs + factors + out) + work
 
 
-def _fit_rank_block(rank: int, block_rows: int, tile: int, factor_rows: int,
-                    num_inputs: int, vmem_budget: int) -> int:
+def _fit_rank_block(rank: int, block_rows: int, tile: int,
+                    factor_rows: Sequence[int], vmem_budget: int) -> int:
     """Widest rank block the compiled kernel accepts (R itself, or a
     multiple of 128 below R) that fits ``vmem_budget``; 0 if none does."""
     cands = [rank] + list(range(_round_up(rank, _LANES) - _LANES, 0,
                                 -_LANES))
     for rb in cands:
-        if kernel_vmem_bytes(block_rows, tile, rb, factor_rows,
-                             num_inputs) <= vmem_budget:
+        if kernel_vmem_bytes(block_rows, tile, rb,
+                             factor_rows) <= vmem_budget:
             return rb
     return 0
 
 
-def _no_fit_error(mode, rank: int, factor_rows: int, vmem_budget: int,
-                  need: int) -> ValueError:
+def _no_fit_error(mode, rank: int, factor_rows: Sequence[int],
+                  vmem_budget: int, need: int) -> ValueError:
+    resident = sum(n for n in factor_rows if n <= GATHER_CHUNK)
     return ValueError(
-        f"pallas MTTKRP cannot plan mode {mode}: its input factors have "
-        f"{factor_rows} rows in all, and at rank {rank} the narrowest "
-        f"rank block needs {need} bytes of VMEM against a budget of "
-        f"{vmem_budget} bytes; use backend='segment' for this tensor")
+        f"pallas MTTKRP cannot plan mode {mode}: its VMEM-resident input "
+        f"factors have {resident} rows in all, and at rank {rank} the "
+        f"narrowest rank block needs {need} bytes of VMEM against a budget "
+        f"of {vmem_budget} bytes; use backend='segment' for this tensor")
 
 
-def auto_rank_block(rank: int, block_rows: int, tile: int, factor_rows: int,
-                    num_inputs: int, *, vmem_budget: int = _VMEM_BYTES,
+def auto_rank_block(rank: int, block_rows: int, tile: int,
+                    factor_rows: Sequence[int], *,
+                    vmem_budget: int = _VMEM_BYTES,
                     mode: int | None = None) -> int:
     """Rank block for the kernel: ``rank`` when the whole rank fits the
     VMEM budget (no tiling), else the widest multiple of 128 that does.
-    Raises ``ValueError`` naming ``mode``, the factor rows and the budget
-    when not even one block fits."""
-    rb = _fit_rank_block(rank, block_rows, tile, factor_rows, num_inputs,
-                         vmem_budget)
+    ``factor_rows`` holds each input factor's row count.  Raises
+    ``ValueError`` naming ``mode``, the resident factor rows and the
+    budget when not even one block fits."""
+    rb = _fit_rank_block(rank, block_rows, tile, factor_rows, vmem_budget)
     if rb == 0:
         need = kernel_vmem_bytes(block_rows, tile, min(rank, _LANES),
-                                 factor_rows, num_inputs)
+                                 factor_rows)
         raise _no_fit_error(mode, rank, factor_rows, vmem_budget, need)
     return rb
 
 
 def estimate_pack_cost(layout, block_rows: int, tile: int, rank: int,
-                       factor_rows: int, *,
+                       factor_rows: Sequence[int], *,
                        vmem_budget: int = _VMEM_BYTES) -> dict:
     """Closed-form kernel cost for a (block_rows, tile) choice — no packing.
 
@@ -378,12 +387,11 @@ def estimate_pack_cost(layout, block_rows: int, tile: int, rank: int,
     slots = G * tile
     pad = 1.0 - layout.nnz / max(slots, 1)
     mxu_factor = max(block_rows, _MXU_DIM) / _MXU_DIM
-    W = layout.nmodes - 1
-    rank_block = _fit_rank_block(rank, block_rows, tile, factor_rows, W,
+    rank_block = _fit_rank_block(rank, block_rows, tile, factor_rows,
                                  vmem_budget)
     num_rank_blocks = -(-rank // rank_block) if rank_block else 0
     vmem = kernel_vmem_bytes(block_rows, tile, rank_block or rank,
-                             factor_rows, W)
+                             factor_rows)
     cost = (slots * mxu_factor + G * _STEP_OVERHEAD_SLOTS) * max(
         num_rank_blocks, 1)
     return {"block_rows": block_rows, "tile": tile, "grid": G,
@@ -394,15 +402,17 @@ def estimate_pack_cost(layout, block_rows: int, tile: int, rank: int,
             "cost": float(cost) if num_rank_blocks else float("inf")}
 
 
-def auto_tiles(layout, rank: int = 32, factor_rows: int | None = None):
+def auto_tiles(layout, rank: int = 32,
+               factor_rows: Sequence[int] | None = None):
     """Pick (block_rows, tile) minimizing the modeled kernel cost under the
     VMEM budget.  The default (128, 256) is good for dense-ish modes; skewed
     or tiny modes prefer smaller row blocks (less slab padding).  Candidates
     whose factors only fit via rank tiling are costed with the re-streaming
     multiplier rather than rejected.  Raises ``ValueError`` naming the mode
-    when no candidate fits the budget."""
+    when no candidate fits the budget.  ``factor_rows`` (each input
+    factor's row count) defaults to the layout's input mode sizes."""
     if factor_rows is None:
-        factor_rows = sum(layout.shape[w] for w in layout.input_modes())
+        factor_rows = [layout.shape[w] for w in layout.input_modes()]
     best = None
     for br, t in tile_candidates():
         c = estimate_pack_cost(layout, br, t, rank, factor_rows)
@@ -412,8 +422,7 @@ def auto_tiles(layout, rank: int = 32, factor_rows: int | None = None):
             best = c
     if best is None:
         br, t = min(tile_candidates())
-        need = kernel_vmem_bytes(br, t, min(rank, _LANES), factor_rows,
-                                 layout.nmodes - 1)
+        need = kernel_vmem_bytes(br, t, min(rank, _LANES), factor_rows)
         raise _no_fit_error(layout.mode, rank, factor_rows, _VMEM_BYTES,
                             need)
     return best["block_rows"], best["tile"]
@@ -440,11 +449,9 @@ def mttkrp_packed(
     block is used and the kernel makes one slab pass per rank block; a
     factor set that fits no block raises (see ``auto_rank_block``)."""
     if rank_block is None:
-        rank = int(factors[0].shape[1])
-        factor_rows = sum(int(f.shape[0]) for f in factors)
         rank_block = auto_rank_block(
-            rank, packed.block_rows, packed.tile, factor_rows, len(factors),
-            mode=packed.mode)
+            int(factors[0].shape[1]), packed.block_rows, packed.tile,
+            [int(f.shape[0]) for f in factors], mode=packed.mode)
     out = mttkrp_pallas(
         jnp.asarray(packed.rb_of),
         jnp.asarray(packed.first),

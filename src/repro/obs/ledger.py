@@ -35,6 +35,9 @@ a call; the plan's cached uploads count once, under the kind ``plan``)
 and ``prepare_s`` (host seconds from a call's entry to its first
 dispatch).  They are always on: a few adds per call under the lock.  The
 benchmark resets the ledger when its window opens and reads them per fit.
+A kind may add counters of its own: ``pallas_gather`` counts, per trace
+of the Pallas MTTKRP, the input factors gathered in HBM (``hbm``) and by
+one-hot matmuls in the kernel (``onehot``).
 """
 from __future__ import annotations
 
@@ -46,7 +49,7 @@ from . import trace as _trace
 
 __all__ = ["RetraceLedger", "LEDGER", "COUNTERS"]
 
-#: What ``RetraceLedger.count`` adds to, per kind, with its zero.
+#: What ``RetraceLedger.count`` adds to for every kind, with its zero.
 COUNTERS = {"dispatches": 0, "h2d_bytes": 0, "prepare_s": 0.0}
 
 
@@ -90,16 +93,15 @@ class RetraceLedger:
                      key=str(key))
         return fn
 
-    def count(self, kind: str, *, dispatches: int = 0, h2d_bytes: int = 0,
-              prepare_s: float = 0.0) -> None:
-        """Add to ``kind``'s counters (see ``COUNTERS``)."""
+    def count(self, kind: str, **amounts: float) -> None:
+        """Add ``amounts`` to ``kind``'s counters: ``COUNTERS`` and any
+        other name the kind counts."""
         with self._lock:
             c = self._counts.get(kind)
             if c is None:
                 c = self._counts[kind] = dict(COUNTERS)
-            c["dispatches"] += dispatches
-            c["h2d_bytes"] += h2d_bytes
-            c["prepare_s"] += prepare_s
+            for name, amount in amounts.items():
+                c[name] = c.get(name, 0) + amount
 
     def reset(self) -> None:
         """Re-baseline: trace counts, the new-block set and the counters
@@ -164,14 +166,15 @@ class RetraceLedger:
         return out
 
     def counts(self, kind: str | None = None) -> dict:
-        """``COUNTERS`` since the last ``reset()`` for one kind, or summed
-        over all kinds."""
-        out = dict(COUNTERS)
+        """Counters since the last ``reset()``: one kind's ``COUNTERS`` and
+        its own counters, or ``COUNTERS`` summed over all kinds."""
         with self._lock:
-            for k, c in self._counts.items():
-                if kind is None or k == kind:
-                    for name in out:
-                        out[name] += c[name]
+            if kind is not None:
+                return {**COUNTERS, **self._counts.get(kind, {})}
+            out = dict(COUNTERS)
+            for c in self._counts.values():
+                for name in out:
+                    out[name] += c[name]
         return out
 
     def kinds(self) -> list[str]:

@@ -215,13 +215,14 @@ def test_pod_plan_dispatch_arithmetic():
 
 def test_pod_sizing_needs_no_kernel_tiling():
     """Pod batch sizing plans no kernel tiling, so a segment pod engine
-    sizes a bucket whose factors exceed the kernel's VMEM budget (enron's
-    244,268-row mode); the bucket's own kernel plan is an error naming
-    the mode."""
+    sizes a bucket whose VMEM-resident factors exceed the kernel's VMEM
+    budget (99 resident 512-row factors per mode; enron's tall modes now
+    stream from an HBM gather and plan); the bucket's own kernel plan is
+    an error naming the mode."""
     from repro.launch.mesh import make_batch_mesh
     from repro.serve import BatchedEngine
 
-    shape = (6_066, 5_699, 244_268, 1_176)
+    shape = (512,) * 100
     eng = BatchedEngine(32, backend="segment", mesh=make_batch_mesh(1),
                         batch_quantum=4)
     assert eng.pod_plan.dispatch_batch(5) == (8, 8)

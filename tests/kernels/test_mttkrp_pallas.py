@@ -1,11 +1,19 @@
 """Pallas kernel vs the pure-jnp oracle: shape/dtype sweeps (interpret mode)."""
+import dataclasses
+
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
-from repro.core import make_plan, mttkrp, random_sparse
+from repro.core import make_plan, mttkrp, plan_bucket, random_sparse
 from repro.kernels import ops as kops
+from repro.kernels.mttkrp_pallas import GATHER_CHUNK, mttkrp_pallas
 from repro.kernels.ops import pack_slabs
+from repro.obs.ledger import LEDGER
+
+# Rows of a factor the kernel cannot hold resident: gathered in HBM.
+TALL = 2 * GATHER_CHUNK + 77
 
 
 def _factors(shape, R, seed=0, dtype=np.float32):
@@ -57,11 +65,10 @@ def test_kernel_blockspec_sweep(block_rows, tile):
 
 
 def test_gather_paths_agree():
-    """The single one-hot gather (factor <= GATHER_CHUNK rows) and the
-    chunked gather (taller factors, zero-padded to a chunk multiple) both
-    match the packed oracle; mode 0 takes both paths in one call."""
-    from repro.kernels.mttkrp_pallas import GATHER_CHUNK
-
+    """The one-hot gather in the kernel (a factor of at most GATHER_CHUNK
+    rows, resident in VMEM) and the HBM gather before it (a taller factor,
+    its rows streamed in packed slot order) both match the packed oracle;
+    mode 0 takes both paths in one call."""
     t = random_sparse((40, 2 * GATHER_CHUNK + 77, 9), 700, seed=7)
     factors = _factors(t.shape, 8, seed=8)
     plan = make_plan(t, kappa=2, block_rows=8, tile=128)
@@ -71,6 +78,160 @@ def test_gather_paths_agree():
         a = np.asarray(kops.mttkrp_packed(packed, in_f))
         b = np.asarray(kops.mttkrp_packed_ref(packed, in_f))
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _packed_pair(plan, mode, factors, **kw):
+    """(kernel, packed oracle) outputs of one mode."""
+    packed = plan.packed(mode)
+    in_f = [factors[w] for w in plan.layouts[mode].input_modes()]
+    return (np.asarray(kops.mttkrp_packed(packed, in_f, **kw)),
+            np.asarray(kops.mttkrp_packed_ref(packed, in_f)))
+
+
+@pytest.mark.parametrize("shape,tall", [
+    ((9, TALL, 7, 5), 1),          # first of mode 0's inputs
+    ((9, 7, TALL, 5), 1),          # middle
+    ((9, 7, 5, TALL), 1),          # last
+    ((9, 600, 530, 520), 3),       # every input
+], ids=["first", "middle", "last", "all"])
+def test_hbm_gather_in_every_position(shape, tall):
+    """A factor taller than GATHER_CHUNK is gathered in HBM wherever it
+    sits among the inputs, and the product keeps the oracle's order."""
+    t = random_sparse(shape, 600, seed=31, distribution="powerlaw")
+    factors = _factors(shape, 8, seed=32)
+    plan = make_plan(t, kappa=2, block_rows=8, tile=128)
+    LEDGER.reset()
+    a, b = _packed_pair(plan, 0, factors)
+    np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    c = LEDGER.counts("pallas_gather")
+    assert (c["hbm"], c["onehot"]) == (tall, 3 - tall)
+
+
+def test_hbm_gather_rank_tiled():
+    """Rank tiling streams the gathered rows one (tile, rank_block) block
+    at a time: bit-identical to the whole rank, and the oracle's answer."""
+    t = random_sparse((40, TALL, 9), 700, seed=33, distribution="powerlaw")
+    factors = _factors(t.shape, 40, seed=34)
+    plan = make_plan(t, kappa=2, block_rows=8, tile=128)
+    for mode in (0, 2):
+        blocked, ref = _packed_pair(plan, mode, factors, rank_block=16)
+        full, _ = _packed_pair(plan, mode, factors)
+        np.testing.assert_array_equal(blocked, full)
+        np.testing.assert_allclose(blocked, ref, rtol=1e-5, atol=1e-5)
+
+
+def test_hbm_gather_vmapped():
+    """The serving path vmaps the kernel over bucket-mates: with a tall
+    factor, a batch of three gives each member bit for bit what a batch
+    of one gives it, and the oracle's answer."""
+    shape, rank, mode = (9, TALL, 7), 8, 0
+    part = plan_bucket(shape, 512, rank)
+    mp = part.modes[mode]
+    plans = [make_plan(random_sparse(shape, 400 + 30 * i, seed=40 + i),
+                       kappa=1, partition=part) for i in range(3)]
+    facs = [_factors(shape, rank, seed=50 + i) for i in range(3)]
+    in_modes = plans[0].layouts[mode].input_modes()
+
+    def one(rb_of, first, idx, vals, lrows, *fs):
+        return mttkrp_pallas(rb_of, first, idx, vals, lrows, list(fs),
+                             num_row_blocks=mp.num_row_blocks,
+                             block_rows=mp.block_rows, tile=mp.tile,
+                             rank_block=mp.rank_block)
+
+    def batch(members):
+        cols = []
+        for i in members:
+            p = plans[i].packed(mode)
+            cols.append([p.rb_of, p.first, p.idx_packed, p.vals_packed,
+                         p.lrows_packed] + [facs[i][w] for w in in_modes])
+        return [jnp.stack([jnp.asarray(a) for a in col])
+                for col in zip(*cols)]
+
+    b3 = np.asarray(jax.vmap(one)(*batch([0, 1, 2])))
+    for i in range(3):
+        b1 = np.asarray(jax.vmap(one)(*batch([i])))[0]
+        np.testing.assert_array_equal(b3[i], b1)
+        ref = np.asarray(kops.mttkrp_packed_ref(
+            plans[i].packed(mode), [facs[i][w] for w in in_modes]))
+        np.testing.assert_allclose(b3[i][:shape[mode]], ref, rtol=1e-5,
+                                   atol=1e-6)
+
+
+def test_hbm_gather_masked_valued():
+    """The masked method's valued MTTKRP scatters fresh values into the
+    slabs and runs the same kernel: with a tall factor it matches the
+    oracle on the slabs holding those values."""
+    from repro.core import als_device
+
+    t = random_sparse((40, TALL, 9), 700, seed=61, distribution="powerlaw")
+    rank = 8
+    factors = _factors(t.shape, rank, seed=62)
+    plan = make_plan(t, kappa=2, block_rows=8, tile=128)
+    mode_data, metas = als_device.collect_structural_mode_data(
+        plan, "pallas", rank)
+    valued = als_device._build_valued_mttkrp("pallas", t.nmodes, t.shape,
+                                             metas, True, None)
+    vals = np.random.default_rng(63).standard_normal(t.nnz).astype(
+        np.float32)
+    for mode in (0, 2):
+        got = np.asarray(valued(mode, mode_data[mode], factors,
+                                jnp.asarray(vals)))
+        packed, lay = plan.packed(mode), plan.layouts[mode]
+        vp = np.zeros_like(packed.vals_packed)
+        vp[0, packed.val_scatter] = vals[lay.perm]
+        rel = np.asarray(kops.mttkrp_packed_ref(
+            dataclasses.replace(packed, vals_packed=vp),
+            [factors[w] for w in lay.input_modes()]))
+        want = np.zeros_like(rel)
+        want[lay.row_perm] = rel
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("mode,hbm,onehot", [(0, 0, 3), (1, 1, 2)])
+def test_pallas_gather_counter(mode, hbm, onehot):
+    """Each trace of the kernel counts its inputs by gather: on a
+    chicago-shaped tensor mode 0's factors (24, 77 and 32 rows) are all
+    gathered in the kernel, and mode 1 gathers the 6,186-row factor in
+    HBM."""
+    shape = (6186, 24, 77, 32)
+    plan = make_plan(random_sparse(shape, 500, seed=71), kappa=1)
+    factors = _factors(shape, 32, seed=72)
+    in_f = [factors[w] for w in plan.layouts[mode].input_modes()]
+    LEDGER.reset()
+    jax.eval_shape(lambda fs: kops.mttkrp_packed(plan.packed(mode), fs),
+                   in_f)
+    c = LEDGER.counts("pallas_gather")
+    assert (c["hbm"], c["onehot"]) == (hbm, onehot)
+
+
+def test_chicago_plans_unchanged_by_hbm_gather():
+    """The VMEM model counts a tall factor as one streamed block, not as
+    resident rows.  Every chicago-r32 candidate tiling fit the budget
+    before, so the plans stay what they were: these literals are the
+    plans of the tree that still gathered tall factors in the kernel."""
+    from repro.core.plan import _UniformModeStats, plan_layout
+
+    shape, nnz = (6186, 24, 77, 32), 5_330_673
+
+    def powerlaw_stats(d):
+        # chicago's row skew: the r-th hottest row has weight (r+1)^-0.5.
+        s = _UniformModeStats(shape, d, nnz)
+        p = (np.arange(shape[d]) + 1.0) ** -0.5
+        cdf = np.concatenate([[0.0], np.cumsum(p / p.sum())])
+        s.row_ptr = np.round(cdf * nnz).astype(np.int64)
+        return s
+
+    def tiling(plans):
+        return [(m.block_rows, m.tile, m.rank_block) for m in plans]
+
+    assert tiling(plan_layout(powerlaw_stats(d), 32) for d in range(4)) == [
+        (128, 512, 32), (32, 512, 32), (128, 512, 32), (32, 512, 32)]
+    # The tiling cpd_als packs with by default (block_rows 128, tile 256).
+    assert tiling(plan_layout(powerlaw_stats(d), 32, block_rows=128,
+                              tile=256) for d in range(4)) == [
+        (128, 256, 32)] * 4
+    assert tiling(plan_bucket(shape, 5_330_688, 32).modes) == [
+        (32, 512, 32), (32, 512, 32), (32, 512, 32), (8, 512, 32)]
 
 
 def test_packing_invariants():
@@ -125,24 +286,33 @@ def test_rank_block_forced_by_vmem_budget():
     rank overflows the budget, and the auto path through mttkrp_packed
     stays correct."""
     # Whole rank fits -> no tiling.
-    assert kops.auto_rank_block(64, 128, 256, 200, 2) == 64
-    # Rank 256 over 40k factor rows: only a 128-column block fits.
-    assert kops.auto_rank_block(256, 128, 256, 40_000, 2) == 128
+    assert kops.auto_rank_block(64, 128, 256, [100, 100]) == 64
+    # Factors gathered in HBM hold one streamed block in VMEM, not their
+    # rows: rank 256 over two 20k-row factors stays whole.
+    assert kops.auto_rank_block(256, 128, 256, [20_000, 20_000]) == 256
+    # Rank 8192 over two resident 500-row factors overflows: the widest
+    # 128-column multiple that fits is chosen.
+    frows = [500, 500]
+    rb = kops.auto_rank_block(8192, 128, 256, frows)
+    assert rb % 128 == 0 and rb < 8192
+    assert (kops.kernel_vmem_bytes(128, 256, rb, frows) <= kops._VMEM_BYTES
+            < kops.kernel_vmem_bytes(128, 256, rb + 128, frows))
     # A rank below 128 lanes is never split: R itself or an error.
-    budget = kops.kernel_vmem_bytes(16, 128, 32, 48, 2)
-    assert kops.auto_rank_block(32, 16, 128, 48, 2,
+    frows = [32, 16]
+    budget = kops.kernel_vmem_bytes(16, 128, 32, frows)
+    assert kops.auto_rank_block(32, 16, 128, frows,
                                 vmem_budget=budget) == 32
     with pytest.raises(ValueError, match="mode 0"):
-        kops.auto_rank_block(32, 16, 128, 48, 2, vmem_budget=budget - 1,
+        kops.auto_rank_block(32, 16, 128, frows, vmem_budget=budget - 1,
                              mode=0)
     # estimate_pack_cost reports the tiling and scales cost by the passes.
     t = random_sparse((64, 32, 16), 800, seed=23)
     plan = make_plan(t, kappa=2, block_rows=16, tile=128)
     lay = plan.layouts[0]
     small = kops.estimate_pack_cost(
-        lay, 16, 128, 256, 48,
-        vmem_budget=kops.kernel_vmem_bytes(16, 128, 128, 48, 2))
-    big = kops.estimate_pack_cost(lay, 16, 128, 256, 48)
+        lay, 16, 128, 256, frows,
+        vmem_budget=kops.kernel_vmem_bytes(16, 128, 128, frows))
+    big = kops.estimate_pack_cost(lay, 16, 128, 256, frows)
     assert small["num_rank_blocks"] > big["num_rank_blocks"] == 1
     assert small["vmem_ok"] and small["cost"] > big["cost"]
     # End-to-end through the mttkrp wrapper with an explicit small block
@@ -161,24 +331,25 @@ def test_planner_only_offers_compilable_blocks():
     for rank in (8, 32, 100, 128, 200, 384):
         for rows in (100, 10_000, 40_000, 80_000):
             try:
-                rb = kops.auto_rank_block(rank, 128, 256, rows, 3)
+                rb = kops.auto_rank_block(rank, 128, 256, [rows // 3] * 3)
             except ValueError:
                 continue
             assert rb == rank or rb % 128 == 0
 
 
 def test_planner_raises_when_factor_cannot_fit_vmem():
-    """A mode whose input factors overflow VMEM at every tiling is an
-    error naming the mode, its factor rows and the budget, not a silent
-    default tiling (enron's 244,268-row mode at rank 32)."""
-    from repro.core.plan import plan_bucket
-
-    shape = (6_066, 5_699, 244_268, 1_176)
+    """A mode whose VMEM-resident input factors overflow VMEM at every
+    tiling is an error naming the mode, those factors' rows and the
+    budget, not a silent default tiling: 99 resident 512-row factors at
+    rank 32.  Enron's 244,268-row mode, which overflowed while tall
+    factors were resident, now plans: its tall factors stream."""
     with pytest.raises(ValueError) as e:
-        plan_bucket(shape, 1 << 20, 32)
+        plan_bucket((GATHER_CHUNK,) * 100, 1 << 20, 32)
     msg = str(e.value)
-    assert "mode 0" in msg and "251143 rows" in msg
+    assert "mode 0" in msg and f"{99 * GATHER_CHUNK} rows" in msg
     assert str(kops._VMEM_BYTES) in msg
+    enron = plan_bucket((6_066, 5_699, 244_268, 1_176), 1 << 20, 32)
+    assert [m.rank_block for m in enron.modes] == [32] * 4
 
 
 def test_interpret_resolves_from_platform():
@@ -211,7 +382,7 @@ def test_auto_tiles_never_worse_than_default_under_model():
     plan = make_plan(t, kappa=4)
     for mode in range(3):
         lay = plan.layouts[mode]
-        frows = sum(t.shape[w] for w in lay.input_modes())
+        frows = [t.shape[w] for w in lay.input_modes()]
         br, tile = kops.auto_tiles(lay, rank=32, factor_rows=frows)
         auto = kops.estimate_pack_cost(lay, br, tile, 32, frows)
         dflt = kops.estimate_pack_cost(lay, kops.DEFAULT_BLOCK_ROWS,

@@ -4,11 +4,12 @@ The TPU compiler refuses things interpret mode accepts (unaligned blocks,
 gathers Mosaic cannot lower, more VMEM than the chip has), so the kernel
 is compiled here at the widths the main path uses: chicago at its full
 FROSTT size under the tiling ``core.plan`` picks, the cpd_als default
-tiling with its 6,186-row gathered factor, the vmapped serving kernel, and
-a rank-tiled kernel.  The topology is described inside a fixture, never
+tiling with its 6,186-row factor gathered in HBM, the vmapped serving
+kernel, and a rank-tiled kernel.  The topology is described inside a fixture, never
 while a module is imported.
 """
 import os
+import re
 
 import pytest
 
@@ -64,10 +65,20 @@ def _compile(one_chip, *, G, tile, block_rows, num_row_blocks, rank,
     return compiled.as_text()
 
 
+def _hbm_gather_before_kernel(text: str) -> bool:
+    """Whether the compiled HLO holds a gather of the ``hbm_gather`` scope,
+    and it comes before the kernel's custom call."""
+    gathers = [m.start() for m in re.finditer(r"\bgather\(", text)
+               if "hbm_gather" in text[m.start():text.find("\n", m.start())]]
+    return bool(gathers) and gathers[0] < text.index("tpu_custom_call")
+
+
 @pytest.mark.parametrize("mode", range(len(CHICAGO)))
 def test_chicago_full_size_planned_tiling(one_chip, mode):
     """Every mode of chicago at full size, R=32, under core.plan's
-    (block_rows, tile, rank_block) and its static slab cap."""
+    (block_rows, tile, rank_block) and its static slab cap.  Modes 1-3
+    gather the 6,186-row factor in HBM ahead of the kernel; mode 0's
+    factors are all short, so it gathers nothing there."""
     mp = plan_bucket(CHICAGO, quantize_nnz(CHICAGO_NNZ), 32).modes[mode]
     text = _compile(one_chip, G=mp.slab_cap, tile=mp.tile,
                     block_rows=mp.block_rows,
@@ -76,18 +87,22 @@ def test_chicago_full_size_planned_tiling(one_chip, mode):
                     factor_rows=[n for w, n in enumerate(CHICAGO)
                                  if w != mode])
     assert "tpu_custom_call" in text
+    assert _hbm_gather_before_kernel(text) == (mode != 0)
+    assert ("hbm_gather" in text) == (mode != 0)
 
 
 def test_gather_of_6186_row_factor(one_chip):
     """Mode 1 under the default tiling cpd_als(backend="pallas") packs
-    with: the 6,186-row factor goes through the chunked one-hot gather."""
+    with: the 6,186-row factor is gathered in HBM and streamed, the other
+    two by one-hot matmuls in the kernel."""
     br, tile = kops.DEFAULT_BLOCK_ROWS, kops.DEFAULT_TILE
     frows = [6186, 77, 32]
-    rb = kops.auto_rank_block(32, br, tile, sum(frows), 3, mode=1)
+    rb = kops.auto_rank_block(32, br, tile, frows, mode=1)
     text = _compile(one_chip, G=1 + CHICAGO_NNZ // tile, tile=tile,
                     block_rows=br, num_row_blocks=1, rank=32,
                     rank_block=rb, factor_rows=frows)
     assert "tpu_custom_call" in text
+    assert _hbm_gather_before_kernel(text)
 
 
 def test_vmapped_kernel_batch_8(one_chip):
@@ -104,12 +119,14 @@ def test_vmapped_kernel_batch_8(one_chip):
 
 
 def test_rank_tiled_kernel(one_chip):
-    """R=256 over factors too tall for the whole rank: the planner picks
-    128-column blocks, and the two-block grid compiles."""
-    frows = [20_000, 20_000]
-    rb = kops.auto_rank_block(256, 128, 256, sum(frows), 2, mode=0)
-    assert rb == 128
+    """R=256 in two 128-column blocks compiles, with one resident factor
+    and one streamed from its HBM gather.  The planner keeps the whole
+    rank for these factors: the tall one holds one (tile, R) block in
+    VMEM, not its 20,000 rows."""
+    frows = [500, 20_000]
+    assert kops.auto_rank_block(256, 128, 256, frows, mode=0) == 256
     text = _compile(one_chip, G=64, tile=256, block_rows=128,
-                    num_row_blocks=8, rank=256, rank_block=rb,
+                    num_row_blocks=8, rank=256, rank_block=128,
                     factor_rows=frows)
     assert "tpu_custom_call" in text
+    assert _hbm_gather_before_kernel(text)

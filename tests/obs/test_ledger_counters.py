@@ -68,6 +68,20 @@ def test_reset_zeroes_the_counters():
                             "prepare_s": 0.0}
 
 
+def test_a_kind_counts_names_of_its_own():
+    led = RetraceLedger()
+    led.count("g", hbm=1, onehot=2)
+    led.count("g", hbm=1, onehot=2)
+    led.count("k", dispatches=1)
+    assert led.counts("g") == {"dispatches": 0, "h2d_bytes": 0,
+                               "prepare_s": 0.0, "hbm": 2, "onehot": 4}
+    assert led.counts() == {"dispatches": 1, "h2d_bytes": 0,
+                            "prepare_s": 0.0}
+    led.reset()
+    assert led.counts("g") == {"dispatches": 0, "h2d_bytes": 0,
+                               "prepare_s": 0.0}
+
+
 def test_batched_engine_counts_its_batch():
     ts = [random_sparse(SHAPE, NNZ - 10 * i, seed=7 + i) for i in range(2)]
     eng = BatchedEngine(RANK, check_every=2)

@@ -18,8 +18,10 @@ a single jit-compiled function with device-carried state:
     sparse fit (<X, X_hat> over nnz + the gram-product model norm) is
     computed on device every sweep; the host only *fetches* it at the
     window boundary, so host syncs drop from 2·N per iteration to 1/k
-    (+1 final materialization).  ``CPDResult.host_syncs`` records the
-    actual count.
+    (+1 final materialization).  A run whose tolerance is off (``tol <=
+    0``) cannot stop early and fetches no fit until the end: its windows
+    are dispatched back to back and it syncs once.
+    ``CPDResult.host_syncs`` records the actual count.
   * compiled sweep blocks are cached per (backend, nmodes, rank, shapes,
     pallas tiling, block length, method): repeated decompositions of
     same-shape tensors — the serving scenario — pay zero retrace.
@@ -48,9 +50,10 @@ profiler trace separates kernel time from solve time and one mode's
 layout from another's (``.../mttkrp/mode0/scatter-add``).  The host side
 of a fit is in ``obs.trace`` spans, which reach the same profiler trace:
 ``als.prepare`` (state, mode data and fit data uploads), one
-``als.window`` per dispatch with ``als.dispatch`` and ``als.fetch``
-(the fit sync) inside, and ``als.readback``.  ``obs.ledger`` counts each
-fit's dispatches, uploaded bytes and preparation time.
+``als.window`` per dispatch with ``als.dispatch`` and, where the run can
+stop early, ``als.fetch`` (the fit sync) inside, and ``als.readback``.
+``obs.ledger`` counts each fit's dispatches, uploaded bytes and
+preparation time.
 
 ``core.cpd.cpd_als`` delegates here by default (``engine="fused"``); the
 original host loop survives as ``engine="host"`` for benchmarking.
@@ -69,6 +72,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.scipy import linalg as jsla
 
+from ..kernels import ops as kops
 from ..kernels import ref as kref
 from ..kernels.mttkrp_pallas import mttkrp_pallas, resolve_interpret
 from ..obs import clock as obs_clock
@@ -742,6 +746,7 @@ class _PreparedFit:
     sweep_k: Callable | None        # the check-window block
     sweep_rem: Callable | None      # the shorter last block
     h2d_bytes: int
+    hbm_gather_bytes: int           # per sweep (Pallas backend; else 0)
 
 
 def _prepare_fit(tensor, rank, spec, *, plan, kappa, n_iters, seed,
@@ -796,11 +801,17 @@ def _prepare_fit(tensor, rank, spec, *, plan, kappa, n_iters, seed,
         backend, N, rank, shapes, pallas_meta, interpret, donate,
         solver, rem, method,
     ) if rem else None
+    gather = 0
+    if backend == "pallas":
+        for d in range(N):
+            rows = [shapes[w] for w in plan.layouts[d].input_modes()]
+            gather += kops.hbm_gather_bytes(plan.packed(d), rows, rank,
+                                            pallas_meta[d][3])
     # The plan's cached arrays are counted by the plan, on first upload.
     kept = plan.device_cache() if plan is not None else ()
     return _PreparedFit(
         state, mode_data_all, fit_data, sweep_k, sweep_rem,
-        uploaded_nbytes((state, mode_data_all, fit_data), kept))
+        uploaded_nbytes((state, mode_data_all, fit_data), kept), gather)
 
 
 def _read_back(fits_dev, state):
@@ -880,6 +891,10 @@ def cpd_als_fused(
                                       prep.fit_data)
 
     n_blocks, rem = divmod(n_iters, check_every)
+    # No fit change is below a tolerance <= 0, so such a run cannot stop
+    # early: its windows go out back to back, with no fit fetched between
+    # them, and the device never waits on a host round trip.
+    fetch = tol > 0 or verbose
     fits_dev: list = []
     host_syncs = 0
     last_fit = -np.inf
@@ -893,16 +908,20 @@ def cpd_als_fused(
         # tests/obs/test_trace.py).
         if tr is None:
             state, fits_blk = fn(state, mode_data_all, fit_data)
-            f = float(fits_blk[-1])             # the only in-loop host sync
+            if fetch:
+                f = float(fits_blk[-1])         # the only in-loop host sync
         else:
             with tr.span("als.window", cat="als", backend=backend,
                          method=method, window=b, sweeps=k):
                 with tr.span("als.dispatch", cat="als"):
                     state, fits_blk = fn(state, mode_data_all, fit_data)
-                with tr.span("als.fetch", cat="als"):
-                    f = float(fits_blk[-1])     # the only in-loop host sync
+                if fetch:
+                    with tr.span("als.fetch", cat="als"):
+                        f = float(fits_blk[-1])  # the only in-loop sync
         fits_dev.append(fits_blk)
         it += k
+        if not fetch:
+            continue
         host_syncs += 1
         if verbose:
             print(f"  ALS iter {it:3d}: fit={f:.6f} ({method}/fused)")
@@ -920,6 +939,9 @@ def cpd_als_fused(
             fits, factors, lam = _read_back(fits_dev, state)
     _LEDGER.count("sweep_block", dispatches=len(fits_dev),
                   h2d_bytes=prep.h2d_bytes, prepare_s=prepare_s)
+    if prep.hbm_gather_bytes:
+        _LEDGER.count("sweep_block",
+                      hbm_gather_bytes=it * prep.hbm_gather_bytes)
 
     return CPDResult(
         factors=factors,

@@ -85,7 +85,8 @@ def cpd_als(
 
     ``engine="fused"`` (default) delegates to the device-resident engine in
     ``als_device`` — the whole N-mode sweep is one jitted computation and
-    the host syncs only every ``check_every`` iterations.  ``engine="host"``
+    the host syncs only every ``check_every`` iterations (with ``tol <= 0``,
+    only once, at the end).  ``engine="host"``
     keeps the original per-mode host loop (useful for benchmarking the
     traffic the fused engine removes).  A custom ``mttkrp_fn(plan, factors,
     mode)`` forces the host loop (benchmarks time alternative formats

@@ -323,6 +323,20 @@ def kernel_vmem_bytes(block_rows: int, tile: int, rank_block: int,
     return 2 * (slabs + factors + out) + work
 
 
+def hbm_gather_bytes(packed: PackedModeLayout, factor_rows: Sequence[int],
+                     rank: int, rank_block: int | None = None) -> int:
+    """Bytes ``mttkrp_pallas`` gathers in HBM for one MTTKRP of ``packed``:
+    for each input factor taller than ``GATHER_CHUNK`` rows, its ``(G*T,
+    R_pad)`` float32 rows in packed slot order, ``R_pad`` being the rank
+    padded to whole rank blocks (``rank_block=None``: the whole rank).
+    These are logical bytes, before XLA pads the rank to 128 lanes."""
+    if rank_block is None or rank_block >= rank:
+        rank_block = rank
+    tall = sum(1 for n in factor_rows if n > GATHER_CHUNK)
+    return (tall * packed.num_slabs * packed.tile
+            * _round_up(rank, rank_block) * 4)
+
+
 def _fit_rank_block(rank: int, block_rows: int, tile: int,
                     factor_rows: Sequence[int], vmem_budget: int) -> int:
     """Widest rank block the compiled kernel accepts (R itself, or a

@@ -100,13 +100,24 @@ def test_als_runner_serves_repeated_requests(mode, engine_name):
 
 def test_fused_scan_window_is_one_dispatch_per_block():
     """The check_every window runs as one lax.scan dispatch: host syncs are
-    ceil(iters/k)+1 and the fit history still has one entry per sweep."""
+    ceil(iters/k)+1 and the fit history still has one entry per sweep.
+    With the tolerance off the windows fetch no fit, and the run syncs
+    once, with the same fits."""
+    from repro.obs.ledger import LEDGER
+
     t = random_sparse((24, 16, 10), 700, seed=9, distribution="powerlaw")
-    res = cpd_als_fused(t, rank=3, n_iters=7, kappa=2, tol=-1.0,
+    # A tolerance above 0 that three sweeps of random data never meet.
+    res = cpd_als_fused(t, rank=3, n_iters=7, kappa=2, tol=1e-12,
                         check_every=3)
     assert res.iters == 7
     assert len(res.fits) == 7              # 3 + 3 + 1 (remainder block)
     assert res.host_syncs == 3 + 1         # one per window + final
+    LEDGER.reset()
+    off = cpd_als_fused(t, rank=3, n_iters=7, kappa=2, tol=-1.0,
+                        check_every=3)
+    assert LEDGER.counts("sweep_block")["dispatches"] == 3
+    assert off.host_syncs == 1             # the final read-back only
+    assert off.fits == res.fits
 
 
 def test_fused_exact_recovery():
@@ -124,3 +135,48 @@ def test_fused_exact_recovery():
     t = SparseTensor(idx, dense.reshape(-1).astype(np.float32), shape)
     res = cpd_als_fused(t, rank=R, n_iters=50, kappa=4, tol=1e-9)
     assert res.fits[-1] > 0.999
+
+
+# FROSTT nips's mode structure at a CPU test's size: three modes taller
+# than GATHER_CHUNK and a 17-row one, so every input of the last mode is
+# gathered in HBM and its kernel has no one-hot input.
+NIPS_LIKE = (600, 700, 1100, 17)
+
+
+def test_fused_pallas_matches_host_loop_on_a_nips_shaped_tensor():
+    """The fused Pallas sweep, with the HBM gather in every mode, against
+    the host loop over the segment MTTKRP with float64 solves, and the
+    bytes it counts as gathered in HBM against the hand count."""
+    from repro.core import make_plan
+    from repro.kernels.mttkrp_pallas import GATHER_CHUNK
+    from repro.obs.ledger import LEDGER
+
+    t = random_sparse(NIPS_LIKE, 6000, seed=16, distribution="powerlaw")
+    plan = make_plan(t, 1)
+    R, iters = 8, 3
+    host = cpd_als(t, rank=R, n_iters=iters, tol=-1.0, backend="segment",
+                   engine="host")
+    LEDGER.reset()
+    fused = cpd_als(t, rank=R, plan=plan, n_iters=iters, tol=-1.0,
+                    backend="pallas")
+    assert fused.engine == "fused" and fused.iters == iters
+    # The gaps are the float32 on-device solves' against float64 ones
+    # (the MTTKRPs agree to float32 rounding); each bound is about 20x
+    # what this seed reads.  Fits: 5e-8 of fits near 1e-3.
+    np.testing.assert_allclose(fused.fits, host.fits, rtol=0, atol=1e-6)
+    # Factors: unit-norm columns, so an absolute gap; reads 3e-6.
+    for Fh, Ff in zip(host.factors, fused.factors):
+        np.testing.assert_allclose(Ff, Fh, rtol=0, atol=5e-5)
+    # Column weights, near 2: reads 2e-6 relative.
+    np.testing.assert_allclose(fused.weights, host.weights, rtol=5e-5)
+    # Per sweep: each input taller than GATHER_CHUNK rows, as (G*T, R)
+    # float32 rows, in every mode.
+    per_sweep = 0
+    for d in range(t.nmodes):
+        p = plan.packed(d)
+        tall = sum(NIPS_LIKE[w] > GATHER_CHUNK
+                   for w in plan.layouts[d].input_modes())
+        assert tall == (3 if d == 3 else 2)
+        per_sweep += tall * p.num_slabs * p.tile * R * 4
+    got = LEDGER.counts("sweep_block")["hbm_gather_bytes"]
+    assert got == iters * per_sweep

@@ -204,6 +204,31 @@ def test_pallas_gather_counter(mode, hbm, onehot):
     assert (c["hbm"], c["onehot"]) == (hbm, onehot)
 
 
+@pytest.mark.parametrize("shape,talls", [
+    ((6186, 24, 77, 32), (0, 1, 1, 1)),       # chicago: one tall factor
+    ((2482, 2862, 14036, 17), (2, 2, 2, 3)),  # nips: mode 3 all tall
+], ids=["chicago", "nips"])
+def test_hbm_gather_bytes_by_hand(shape, talls):
+    """Per mode, G*T*R_pad*4 bytes for each input taller than
+    GATHER_CHUNK rows, the same inputs the kernel's trace gathers in HBM
+    (the rest by one-hot matmuls); rank 40 in blocks of 16 pads to 48
+    columns."""
+    plan = make_plan(random_sparse(shape, 700, seed=73), kappa=1)
+    for mode, tall in enumerate(talls):
+        p = plan.packed(mode)
+        rows = [shape[w] for w in plan.layouts[mode].input_modes()]
+        slots = p.num_slabs * p.tile
+        assert kops.hbm_gather_bytes(p, rows, 32) == tall * slots * 32 * 4
+        assert kops.hbm_gather_bytes(p, rows, 32, 32) == tall * slots * 128
+        assert kops.hbm_gather_bytes(p, rows, 40, 16) == tall * slots * 192
+        assert kops.hbm_gather_bytes(p, rows, 40, 128) == tall * slots * 160
+        LEDGER.reset()
+        jax.eval_shape(lambda fs: kops.mttkrp_packed(p, fs),
+                       [jnp.zeros((n, 32), jnp.float32) for n in rows])
+        c = LEDGER.counts("pallas_gather")
+        assert (c["hbm"], c["onehot"]) == (tall, 3 - tall)
+
+
 def test_chicago_plans_unchanged_by_hbm_gather():
     """The VMEM model counts a tall factor as one streamed block, not as
     resident rows.  Every chicago-r32 candidate tiling fit the budget

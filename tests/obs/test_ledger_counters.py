@@ -39,7 +39,8 @@ def test_fit_h2d_bytes_equal_the_hand_count(backend):
     want = _state_bytes() + _fit_data_bytes(t.nnz) + row_perm
     c = LEDGER.counts("sweep_block")
     assert c["h2d_bytes"] == want
-    assert c["dispatches"] == 2 == res.host_syncs - 1
+    assert c["dispatches"] == 2
+    assert res.host_syncs == 1      # tol < 0: no window fetches its fit
     assert c["prepare_s"] > 0.0
     assert LEDGER.counts("plan")["h2d_bytes"] == 0
     assert LEDGER.counts() == c

@@ -32,11 +32,14 @@ def _inside(inner: dict, outer: dict) -> bool:
             <= outer["ts"] + outer["dur"] + 1e-3)
 
 
-def _profiled_fit(dump_dir, **kw):
+def _profiled_fit(dump_dir, tol=1e-12, **kw):
+    """Three one-sweep windows; the default tolerance is above 0, so every
+    window fetches its fit, and three sweeps of random data never meet
+    it."""
     t = random_sparse((10, 8, 6), 200, seed=3)
     jax.profiler.start_trace(str(dump_dir))
     try:
-        res = cpd_als(t, 4, n_iters=3, tol=-1.0, check_every=1, **kw)
+        res = cpd_als(t, 4, n_iters=3, tol=tol, check_every=1, **kw)
     finally:
         jax.profiler.stop_trace()
     return res, _host_events(dump_dir)
@@ -68,6 +71,17 @@ def test_capture_holds_the_fit_spans_by_bare_name(tmp_path):
     assert fit["args"]["backend"] == "segment"
     assert int(fit["args"]["nnz"]) == 200
     assert int(prep["args"]["h2d_bytes"]) > 0
+
+
+def test_a_run_that_cannot_stop_fetches_no_fit(tmp_path):
+    """With the tolerance off the windows are dispatched back to back: no
+    ``als.fetch`` span, one host sync, the fits read back at the end."""
+    res, events = _profiled_fit(tmp_path, tol=-1.0)
+    names = [e["name"] for e in events]
+    assert names.count("als.window") == names.count("als.dispatch") == 3
+    assert "als.fetch" not in names
+    assert names.count("als.readback") == 1
+    assert res.host_syncs == 1 and len(res.fits) == 3
 
 
 def test_no_capture_no_tracer_means_no_spans():

@@ -26,7 +26,8 @@ def test_phases_run_on_cpu_at_tiny_size():
     b = chip_smoke.phase_als(tensor, rank=8, iters=3)
     for backend in chip_smoke.BACKENDS:
         assert b[backend]["max_fit_gap"] <= chip_smoke.FIT_TOL
-        assert b[backend]["host_syncs"] == 2      # one window + final
+        # tol=-1: the window fetches no fit; one sync, the final read-back
+        assert b[backend]["host_syncs"] == 1
     tensors = chip_smoke.serve_stream(per_family=2)
     for backend in chip_smoke.BACKENDS:
         c = chip_smoke.phase_service(tensors, backend)

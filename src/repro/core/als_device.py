@@ -15,10 +15,13 @@ a single jit-compiled function with device-carried state:
   * the ``check_every`` iterations between convergence checks run as ONE
     dispatch: a ``lax.scan`` over the sweep body, so the host pays a
     single call per check window instead of one per iteration.  The
-    sparse fit (<X, X_hat> over nnz + the gram-product model norm) is
-    computed on device every sweep; the host only *fetches* it at the
-    window boundary, so host syncs drop from 2·N per iteration to 1/k
-    (+1 final materialization).  A run whose tolerance is off (``tol <=
+    fit (<X, X_hat> + the gram-product model norm) is computed on device
+    every sweep: the CP sweep reads <X, X_hat> from the last mode's
+    MTTKRP output at O(I_N·R), while method sweeps gather every factor
+    at every nonzero (``SweepContext.sparse_fit`` / ``weighted_fit``).
+    The host only *fetches* the fit at the window boundary, so host
+    syncs drop from 2·N per iteration to 1/k (+1 final
+    materialization).  A run whose tolerance is off (``tol <=
     0``) cannot stop early and fetches no fit until the end: its windows
     are dispatched back to back and it syncs once.
     ``CPDResult.host_syncs`` records the actual count.
@@ -322,25 +325,36 @@ def normalize_columns(Yd):
     return Yd / lam, lam
 
 
+def _fit_from_innerprod(ip, grams, weights, norm_x_sq, rank: int):
+    """``1 - ||X - X_hat|| / ||X||`` from ``ip = <X, X_hat>``, the model
+    norm ``weights' (*_d A_d'A_d) weights`` from the grams, and
+    ``norm_x_sq``: the tail every unweighted fit shares."""
+    V = _hadamard_grams(grams, rank)
+    model_sq = weights @ V @ weights
+    resid_sq = jnp.maximum(norm_x_sq - 2.0 * ip + model_sq, 0.0)
+    return 1.0 - jnp.sqrt(resid_sq) / jnp.maximum(
+        jnp.sqrt(norm_x_sq), 1e-12)
+
+
 def _build_sparse_fit(nmodes: int, rank: int, axis: str | None):
     """On-device sparse fit (jnp ports of cpd._innerprod_sparse /
     cpd._model_norm_sq): no dense reconstruction, no host round-trip.
+    Gathers every factor at every nonzero for ``<X, X_hat>``: method
+    sweeps use it; the inline CP sweep reads ``<X, X_hat>`` from its last
+    mode's MTTKRP (``build_sweep_fn``).
     Zero-valued padding entries (serve.buckets) contribute exactly +0.0
     to both the Hadamard accumulation and the inner product."""
 
     def sparse_fit(factors, grams, weights, fit_data):
         indices, values, norm_x_sq = fit_data
+        _LEDGER.count("sweep_fit", gather=1)
         acc = jnp.ones((values.shape[0], rank), jnp.float32)
         for d in range(nmodes):
             acc = acc * factors[d][indices[:, d]]
         ip = values @ (acc @ weights)
         if axis is not None:          # nnz are sharded across devices
             ip = lax.psum(ip, axis)
-        V = _hadamard_grams(grams, rank)
-        model_sq = weights @ V @ weights
-        resid_sq = jnp.maximum(norm_x_sq - 2.0 * ip + model_sq, 0.0)
-        return 1.0 - jnp.sqrt(resid_sq) / jnp.maximum(
-            jnp.sqrt(norm_x_sq), 1e-12)
+        return _fit_from_innerprod(ip, grams, weights, norm_x_sq, rank)
 
     return sparse_fit
 
@@ -355,6 +369,7 @@ def _build_weighted_fit(nmodes: int, rank: int, axis: str | None):
 
     def weighted_fit(factors, weights, fit_data):
         indices, values, ew, norm_x_sq = fit_data
+        _LEDGER.count("sweep_fit", gather=1)
         acc = jnp.ones((values.shape[0], rank), jnp.float32)
         for d in range(nmodes):
             acc = acc * factors[d][indices[:, d]]
@@ -419,7 +434,10 @@ class SweepContext:
     mttkrp_valued: Callable   # (d, mode_data, factors, vals) -> (I_d, R)
     solve: Callable           # (M, V) -> Yd  (ridge + pinv rescue)
     normalize: Callable       # (Yd) -> (Yd, lam)  (dead-column guard)
-    sparse_fit: Callable      # (factors, grams, weights, fit_data) -> fit
+    # (factors, grams, weights, fit_data) -> fit: gathers every factor
+    # at every nonzero (nncp).  The inline CP sweep reads <X, X_hat>
+    # from its last mode's MTTKRP instead.
+    sparse_fit: Callable
     weighted_fit: Callable    # (factors, weights, fit_data4) -> fit
     hadamard: Callable        # (grams, exclude=None) -> (R, R)
 
@@ -464,7 +482,9 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
 
     ``axis``: a mesh axis name — mode data and fit data are then
     device-local shards and the sweep ``psum``s the partial MTTKRP output
-    and the fit inner product over that axis (the distributed path).
+    over that axis (the distributed path); the CP fit, read from that
+    combined output, needs no collective of its own, while a method's
+    gathered fit ``psum``s its inner product.
     ``fallback``: 'cond' guards the solve with the pinv rescue (the
     sequential default); 'none' omits it so a batch-level all-finite cond
     can be hoisted AROUND the whole window (``serve.batched_engine``) —
@@ -490,7 +510,6 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
     one_mttkrp = _build_one_mttkrp(backend, nmodes, shapes, pallas_meta,
                                    interpret, axis, collectives)
     solve = _build_solver(rank, solver, fallback)
-    sparse_fit = _build_sparse_fit(nmodes, rank, axis)
 
     if method != "cp":
         from ..methods import get_method   # lazy: core must import clean
@@ -509,7 +528,7 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
             solver=solver, fallback=fallback, axis=axis,
             one_mttkrp=one_mttkrp, mttkrp_valued=mttkrp_valued,
             solve=solve, normalize=normalize_columns,
-            sparse_fit=sparse_fit,
+            sparse_fit=_build_sparse_fit(nmodes, rank, axis),
             weighted_fit=_build_weighted_fit(nmodes, rank, axis),
             hadamard=functools.partial(_hadamard_grams, rank=rank),
         )
@@ -528,8 +547,15 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
             factors[d] = Yd
             grams[d] = Yd.T @ Yd
             weights = lam
+        # The last mode's M was computed against factors 0..N-2 as they
+        # leave this sweep, so <X, X_hat> = sum_{i,r} M * A_{N-1} * lam.
+        # M is already combined across ``axis``: the product is global.
+        # The COO list in ``fit_data`` is not read.
+        _LEDGER.count("sweep_fit", mttkrp=1)
+        _, _, norm_x_sq = fit_data
         with jax.named_scope("fit"):
-            fit = sparse_fit(factors, grams, weights, fit_data)
+            ip = jnp.sum(M * factors[-1], axis=0) @ weights
+            fit = _fit_from_innerprod(ip, grams, weights, norm_x_sq, rank)
         return (tuple(factors), tuple(grams), weights), fit
 
     return sweep

@@ -37,7 +37,11 @@ dispatch).  They are always on: a few adds per call under the lock.  The
 benchmark resets the ledger when its window opens and reads them per fit.
 A kind may add counters of its own: ``pallas_gather`` counts, per trace
 of the Pallas MTTKRP, the input factors gathered in HBM (``hbm``) and by
-one-hot matmuls in the kernel (``onehot``); ``sweep_block`` counts
+one-hot matmuls in the kernel (``onehot``); ``sweep_fit`` counts, per
+trace of a fused sweep, how its fit reads ``<X, X_hat>``: ``mttkrp``
+from the last mode's MTTKRP output (the inline CP sweep), ``gather``
+by gathering every factor at every nonzero (method sweeps: nncp,
+masked); ``sweep_block`` counts
 ``hbm_gather_bytes``, the factor rows the fused sweeps gathered in HBM
 over the iterations each fit ran (``kernels.ops.hbm_gather_bytes``), for
 the fits that gathered any.

@@ -82,6 +82,37 @@ def test_distributed_fused_matches_single_device():
     assert "PASS" in out
 
 
+def test_distributed_cp_fit_is_read_once_from_the_combined_mttkrp():
+    """The CP fit reads <X, X_hat> from the last mode's MTTKRP after its
+    cross-device combine, so it is global with no psum of its own: the
+    8-device fit history equals the single-device fused run's under both
+    collectives (a second psum would scale the inner product by 8), and
+    the sweep's trace counts the MTTKRP-read fit."""
+    out = run_py("""
+        import numpy as np
+        from repro.core import cpd_als_fused, random_sparse
+        from repro.core.distributed import cpd_als_distributed
+        from repro.obs.ledger import LEDGER
+
+        t = random_sparse((40, 30, 12, 6), 1800, seed=8,
+                          distribution="powerlaw")
+        ref = cpd_als_fused(t, rank=4, n_iters=5, tol=-1.0, seed=3,
+                            check_every=5)
+        assert ref.fits[-1] > 0.01, ref.fits
+        for collective in ("psum", "gather"):
+            LEDGER.reset()
+            res = cpd_als_distributed(t, rank=4, n_iters=5, tol=-1.0,
+                                      seed=3, check_every=5,
+                                      collective=collective)
+            np.testing.assert_allclose(res.fits, ref.fits, rtol=1e-4,
+                                       atol=1e-4)
+            c = LEDGER.counts("sweep_fit")
+            assert c.get("mttkrp", 0) >= 1 and c.get("gather", 0) == 0, c
+        print("PASS", ref.fits[-1])
+    """)
+    assert "PASS" in out
+
+
 def test_elastic_restore_different_topology(tmp_path):
     """Checkpoint on an 8-device mesh, restore onto 4 devices."""
     code1 = f"""

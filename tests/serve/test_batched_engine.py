@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.core import cpd_als_fused, make_plan, random_sparse
+from repro.obs.ledger import LEDGER
 from repro.serve import BatchedEngine, batched_cache_stats
 
 # Three bucket shapes (incl. a 4-mode one) for the equivalence matrix.
@@ -46,6 +47,29 @@ def test_batched_coo_backend_matches_sequential():
     for i, t in enumerate(ts):
         ref = cpd_als_fused(t, R, kappa=2, n_iters=3, tol=-1.0, seed=i,
                             backend="coo")
+        np.testing.assert_allclose(batch[i].fits, ref.fits,
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("backend", ["segment", "coo"])
+def test_padded_bucket_cp_fit_matches_sequential(backend):
+    """The vmapped CP sweep reads each tensor's fit from its last MTTKRP:
+    zero-valued padding adds exactly nothing to it, so a bucket padded
+    well past every tensor (one at under half the cap) reports the
+    sequential fused fits, and each trace counts the MTTKRP-read fit."""
+    shape, R, cap = (11, 9, 7, 5), 3, 400
+    ts = [random_sparse(shape, n, seed=50 + i, distribution="powerlaw")
+          for i, n in enumerate((370, 310, 150))]
+    eng = BatchedEngine(rank=R, kappa=2, backend=backend, check_every=3)
+    LEDGER.reset()
+    batch = eng.decompose_batch(ts, n_iters=6, tol=-1.0, seeds=[5, 6, 7],
+                                nnz_cap=cap)
+    c = LEDGER.counts("sweep_fit")
+    assert c.get("mttkrp", 0) >= 1 and c.get("gather", 0) == 0, c
+    for i, t in enumerate(ts):
+        ref = cpd_als_fused(t, R, kappa=2, n_iters=6, tol=-1.0, seed=5 + i,
+                            backend=backend, check_every=3)
+        assert batch[i].iters == ref.iters == 6
         np.testing.assert_allclose(batch[i].fits, ref.fits,
                                    rtol=1e-5, atol=1e-5)
 

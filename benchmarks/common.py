@@ -36,6 +36,7 @@ import jax.numpy as jnp
 from repro.core import SparseTensor, frostt_like, make_plan, mttkrp
 from repro.core.layout import build_mode_layout
 from repro.core.load_balance import Scheme
+from repro.core.mttkrp import layout_arrays, mttkrp_mode
 from repro.kernels import ref as kref
 
 # CI-sized FROSTT stand-ins (same mode-count / dimension ratios, nnz
@@ -141,13 +142,10 @@ class CSFLikeEngine:
     def __call__(self, plan, factors, mode):
         if mode == 0:
             lay = self.layout0
-            in_modes = lay.input_modes()
-            out = kref.mttkrp_sorted_segments(
-                jnp.asarray(lay.indices[:, in_modes]), jnp.asarray(lay.rows),
-                jnp.asarray(lay.values), [jnp.asarray(factors[w]) for w in in_modes],
-                lay.num_rows)
-            res = jnp.zeros_like(out).at[jnp.asarray(lay.row_perm)].set(out)
-            return res
+            md = tuple(jnp.asarray(a) for a in layout_arrays("segment", lay))
+            return mttkrp_mode("segment", 0, md,
+                               [jnp.asarray(f) for f in factors],
+                               lay.num_rows)
         # other modes: traverse the mode-0-ordered copy, scatter-add
         lay = self.layout0
         idx = jnp.asarray(lay.indices)

@@ -58,6 +58,12 @@ stop early, ``als.fetch`` (the fit sync) inside, and ``als.readback``.
 ``obs.ledger`` counts each fit's dispatches, uploaded bytes and
 preparation time.
 
+The MTTKRP itself is ``core.mttkrp.mttkrp_mode``, the one dispatch
+every engine runs; this module only wraps it in the per-mode scope and,
+under a mesh axis, hands it the cross-device combine.  The host window
+loop (``_run_fit``) serves this module's ``cpd_als_fused`` and
+``core.distributed.cpd_als_distributed`` alike.
+
 ``core.cpd.cpd_als`` delegates here by default (``engine="fused"``); the
 original host loop survives as ``engine="host"`` for benchmarking.
 """
@@ -76,14 +82,20 @@ from jax import lax
 from jax.scipy import linalg as jsla
 
 from ..kernels import ops as kops
-from ..kernels import ref as kref
-from ..kernels.mttkrp_pallas import mttkrp_pallas, resolve_interpret
+from ..kernels.mttkrp_pallas import resolve_interpret
+from ..kernels.ref import cp_model_at_coords
 from ..obs import clock as obs_clock
 from ..obs import trace as obs_trace
 from ..obs.ledger import LEDGER as _LEDGER
 from .coo import SparseTensor
 from .cpd import CPDResult
-from .mttkrp import MTTKRPPlan, make_plan
+from .mttkrp import (MTTKRPPlan, collect_mode_data, layout_values,
+                     make_plan, mttkrp_mode, owned_rows_combine,
+                     psum_combine)
+
+# Value-baked mode data for a plan, under the name the benchmark's own
+# code reads.
+_collect_mode_data = collect_mode_data
 
 _RIDGE_REL = 1e-10
 
@@ -115,163 +127,49 @@ def resolve_solver(solver: str) -> str:
 # ---------------------------------------------------------------------------
 
 
-def _mode_scope(mttkrp_fn):
-    """Run ``mttkrp_fn(d, ...)`` under ``jax.named_scope(f"mode{d}")``, so
-    that the device trace names each mode's operations."""
+def _build_mttkrp(backend: str, shapes: tuple[int, ...],
+                  pallas_meta: tuple | None, interpret: bool,
+                  axis: str | None,
+                  collectives: tuple[str, ...] | None = None):
+    """``mttkrp(d, mode_data, factors, vals=None) -> (I_d, R)``: the
+    sweep's MTTKRP, ``core.mttkrp.mttkrp_mode`` under
+    ``jax.named_scope(f"mode{d}")``, so that the device trace names each
+    mode's operations.
 
-    @functools.wraps(mttkrp_fn)
-    def run(d, *args):
+    Without ``vals`` the values are baked into the mode data (the CP
+    layout contract).  With ``vals`` the mode data is structural
+    (``core.mttkrp.MODE_FIELDS``) and ``vals`` a fresh canonical-order
+    value vector (e.g. the masked method's per-sweep residual), which is
+    permuted or scattered into layout form first.
+
+    With ``axis`` (the distributed shard_map path) mode data is the
+    device-local shard and the partial outputs combine across ``axis``:
+    by ``psum``, or, for a mode whose ``collectives`` entry is "gather"
+    (scheme 1 only), by all-gathering each device's owned row slice,
+    whose indices ride as two extra arrays after the value-baked segment
+    fields (see ``core.plan.DeviceShards.own_rows``).  Valued calls
+    receive ``vals`` in layout-shard order already (each device evaluates
+    its residual at its own shard's coordinates, see ``methods.masked``).
+    """
+
+    def combine(d, mode_data):
+        if axis is None:
+            return None
+        if collectives is not None and collectives[d] == "gather":
+            return owned_rows_combine(axis, *mode_data[4:6], shapes[d])
+        return psum_combine(axis)
+
+    def mttkrp(d, mode_data, factors, vals=None):
         with jax.named_scope(f"mode{d}"):
-            return mttkrp_fn(d, *args)
+            if vals is not None and axis is None:
+                vals = layout_values(backend, mode_data, vals)
+            return mttkrp_mode(
+                backend, d, mode_data, factors, shapes[d],
+                meta=None if pallas_meta is None else pallas_meta[d],
+                interpret=interpret, vals=vals,
+                combine=combine(d, mode_data))
 
-    return run
-
-
-def _build_one_mttkrp(backend: str, nmodes: int, shapes: tuple[int, ...],
-                      pallas_meta: tuple | None, interpret: bool,
-                      axis: str | None,
-                      collectives: tuple[str, ...] | None = None):
-    """``one_mttkrp(d, mode_data, factors) -> (I_d, R)`` with values baked
-    into the mode data (the CP layout contract):
-
-      segment: (idx, rows, vals, row_perm)
-      pallas:  (rb_of, first, idx_packed, vals_packed, lrows_packed, row_perm)
-      coo:     (indices, values)
-
-    ``collectives`` (distributed segment path only): per-mode choice of
-    how partial outputs combine across ``axis`` — "psum" (the default,
-    works for both partition schemes) or "gather" (scheme 1 only: each
-    device all-gathers just its OWNED row slice and scatters through the
-    gathered destination map, moving ~1/kappa of the psum payload; mode
-    data widens to ``(idx, rows, vals, row_perm, own_rows, gather_dst)``,
-    see ``core.plan.DeviceShards.own_rows``).
-    """
-    in_modes = [tuple(w for w in range(nmodes) if w != d)
-                for d in range(nmodes)]
-
-    @_mode_scope
-    def one_mttkrp(d, mode_data, factors):
-        """(I_d, R) f32 in ORIGINAL row order, entirely on device."""
-        if backend == "segment":
-            if (axis is not None and collectives is not None
-                    and collectives[d] == "gather"):
-                idx, rows, vals, row_perm, own_rows, gather_dst = mode_data
-                out = kref.mttkrp_sorted_segments(
-                    idx, rows, vals,
-                    [factors[w] for w in in_modes[d]], shapes[d]
-                )
-                # Scheme-1 partials have support only on this device's
-                # owned relabeled rows: gather those slices plus their
-                # original-row destinations and scatter into a buffer
-                # with one dummy row (I_d) absorbing the padding slots.
-                own = out[own_rows]                        # (rows_cap, R)
-                g_vals = lax.all_gather(own, axis)         # (κ, cap, R)
-                g_dst = lax.all_gather(gather_dst, axis)   # (κ, cap)
-                full = jnp.zeros((shapes[d] + 1, out.shape[-1]), out.dtype)
-                full = full.at[g_dst.reshape(-1)].set(
-                    g_vals.reshape(-1, out.shape[-1]))
-                return full[: shapes[d]]
-            idx, rows, vals, row_perm = mode_data
-            out = kref.mttkrp_sorted_segments(
-                idx, rows, vals, [factors[w] for w in in_modes[d]], shapes[d]
-            )
-            if axis is not None:      # combine per-device partials
-                out = lax.psum(out, axis)
-            return jnp.zeros_like(out).at[row_perm].set(out)
-        if backend == "pallas":
-            rb_of, first, idxp, valsp, lrowsp, row_perm = mode_data
-            nrb, br, tile, rblk = pallas_meta[d]
-            out = mttkrp_pallas(
-                rb_of, first, idxp, valsp, lrowsp,
-                [factors[w] for w in in_modes[d]],
-                num_row_blocks=nrb, block_rows=br, tile=tile,
-                rank_block=rblk, interpret=interpret,
-            )[: shapes[d]]
-            if axis is not None:
-                out = lax.psum(out, axis)
-            return jnp.zeros_like(out).at[row_perm].set(out)
-        if backend == "coo":
-            indices, values = mode_data
-            out = kref.mttkrp_coo(
-                indices, values, list(factors), d, shapes[d]
-            )
-            if axis is not None:
-                out = lax.psum(out, axis)
-            return out
-        raise ValueError(f"unknown backend {backend!r}")
-
-    return one_mttkrp
-
-
-def _build_valued_mttkrp(backend: str, nmodes: int, shapes: tuple[int, ...],
-                         pallas_meta: tuple | None, interpret: bool,
-                         axis: str | None):
-    """``mttkrp_valued(d, mode_data, factors, vals) -> (I_d, R)``: the
-    mask-weighted entry point.  Mode data carries only the STRUCTURAL
-    layout arrays; a fresh canonical-order value vector (e.g. the masked
-    method's per-sweep residual) is threaded through the same kernels:
-
-      segment: (idx, rows, row_perm, perm)            vals_layout = vals[perm]
-      pallas:  (rb_of, first, idx_packed, lrows_packed,
-                row_perm, perm, val_scatter)           scatter into the slabs
-      coo:     (indices,)                              canonical order already
-
-    With ``axis`` (the distributed shard_map path, segment backend only)
-    the contract changes: mode data is the device-local structural shard
-    ``(idx, rows, row_perm)`` and ``vals`` arrives in LAYOUT-SHARD order
-    (each device evaluates its residual at its own shard's coordinates —
-    see ``methods.masked``), so no canonical->layout permutation exists;
-    the partial outputs are ``psum``-combined over the axis.
-    """
-    in_modes = [tuple(w for w in range(nmodes) if w != d)
-                for d in range(nmodes)]
-
-    if axis is not None:
-        if backend != "segment":
-            raise NotImplementedError(
-                "the distributed valued MTTKRP runs on the segment backend "
-                f"(shard_map path), got {backend!r}")
-
-        @_mode_scope
-        def mttkrp_valued_dist(d, mode_data, factors, vals):
-            idx, rows, row_perm = mode_data
-            out = kref.mttkrp_sorted_segments(
-                idx, rows, vals, [factors[w] for w in in_modes[d]], shapes[d]
-            )
-            out = lax.psum(out, axis)
-            return jnp.zeros_like(out).at[row_perm].set(out)
-
-        return mttkrp_valued_dist
-
-    @_mode_scope
-    def mttkrp_valued(d, mode_data, factors, vals):
-        if backend == "segment":
-            idx, rows, row_perm, perm = mode_data
-            out = kref.mttkrp_sorted_segments(
-                idx, rows, vals[perm],
-                [factors[w] for w in in_modes[d]], shapes[d]
-            )
-            return jnp.zeros_like(out).at[row_perm].set(out)
-        if backend == "pallas":
-            rb_of, first, idxp, lrowsp, row_perm, perm, scatter = mode_data
-            nrb, br, tile, rblk = pallas_meta[d]
-            valsp = jnp.zeros((1, idxp.shape[-1]), jnp.float32)
-            valsp = valsp.at[0, scatter].set(vals[perm])
-            out = mttkrp_pallas(
-                rb_of, first, idxp, valsp, lrowsp,
-                [factors[w] for w in in_modes[d]],
-                num_row_blocks=nrb, block_rows=br, tile=tile,
-                rank_block=rblk, interpret=interpret,
-            )[: shapes[d]]
-            return jnp.zeros_like(out).at[row_perm].set(out)
-        if backend == "coo":
-            (indices,) = mode_data
-            return kref.mttkrp_coo(
-                indices, vals, list(factors), d, shapes[d]
-            )
-        raise ValueError(f"unknown backend {backend!r}")
-
-    return mttkrp_valued
+    return mttkrp
 
 
 def _hadamard_grams(grams, rank: int, exclude: int | None = None):
@@ -336,7 +234,7 @@ def _fit_from_innerprod(ip, grams, weights, norm_x_sq, rank: int):
         jnp.sqrt(norm_x_sq), 1e-12)
 
 
-def _build_sparse_fit(nmodes: int, rank: int, axis: str | None):
+def _build_sparse_fit(rank: int, axis: str | None):
     """On-device sparse fit (jnp ports of cpd._innerprod_sparse /
     cpd._model_norm_sq): no dense reconstruction, no host round-trip.
     Gathers every factor at every nonzero for ``<X, X_hat>``: method
@@ -348,10 +246,7 @@ def _build_sparse_fit(nmodes: int, rank: int, axis: str | None):
     def sparse_fit(factors, grams, weights, fit_data):
         indices, values, norm_x_sq = fit_data
         _LEDGER.count("sweep_fit", gather=1)
-        acc = jnp.ones((values.shape[0], rank), jnp.float32)
-        for d in range(nmodes):
-            acc = acc * factors[d][indices[:, d]]
-        ip = values @ (acc @ weights)
+        ip = values @ cp_model_at_coords(indices, factors, weights)
         if axis is not None:          # nnz are sharded across devices
             ip = lax.psum(ip, axis)
         return _fit_from_innerprod(ip, grams, weights, norm_x_sq, rank)
@@ -359,7 +254,7 @@ def _build_sparse_fit(nmodes: int, rank: int, axis: str | None):
     return sparse_fit
 
 
-def _build_weighted_fit(nmodes: int, rank: int, axis: str | None):
+def _build_weighted_fit(axis: str | None):
     """Observed-only weighted fit shared by the masked method across every
     execution path:  ``1 - sqrt(sum_e w_e (x_e - model_e)^2) /
     sqrt(sum_e w_e x_e^2)``.  ``fit_data = (indices, values,
@@ -370,10 +265,7 @@ def _build_weighted_fit(nmodes: int, rank: int, axis: str | None):
     def weighted_fit(factors, weights, fit_data):
         indices, values, ew, norm_x_sq = fit_data
         _LEDGER.count("sweep_fit", gather=1)
-        acc = jnp.ones((values.shape[0], rank), jnp.float32)
-        for d in range(nmodes):
-            acc = acc * factors[d][indices[:, d]]
-        resid = values - acc @ weights
+        resid = values - cp_model_at_coords(indices, factors, weights)
         resid_sq = jnp.sum(ew * resid * resid)
         if axis is not None:
             resid_sq = lax.psum(resid_sq, axis)
@@ -414,6 +306,18 @@ def normalize_entry_weights(w: np.ndarray) -> np.ndarray:
     return (w / np.float32(m)).astype(np.float32) if m > 1.0 else w
 
 
+def _checked_entry_weights(spec, method: str, nnz: int, weights):
+    """A front door's ``weights``, validated and normalized; refused for
+    a method without a weighted fit.  ``None`` stays ``None``."""
+    if weights is None:
+        return None
+    if spec is None or not spec.weighted_fit:
+        raise ValueError(
+            f"per-entry weights require a weighted-fit method "
+            f"(e.g. 'masked'), got method={method!r}")
+    return normalize_entry_weights(validate_entry_weights(nnz, weights))
+
+
 @dataclasses.dataclass(frozen=True)
 class SweepContext:
     """Everything a decomposition method needs to build its sweep on the
@@ -430,8 +334,9 @@ class SweepContext:
     solver: str
     fallback: str
     axis: str | None
-    one_mttkrp: Callable      # (d, mode_data, factors) -> (I_d, R)
-    mttkrp_valued: Callable   # (d, mode_data, factors, vals) -> (I_d, R)
+    # (d, mode_data, factors[, vals]) -> (I_d, R): value-baked mode data
+    # without vals, structural mode data with them (``_build_mttkrp``).
+    one_mttkrp: Callable
     solve: Callable           # (M, V) -> Yd  (ridge + pinv rescue)
     normalize: Callable       # (Yd) -> (Yd, lam)  (dead-column guard)
     # (factors, grams, weights, fit_data) -> fit: gathers every factor
@@ -443,7 +348,8 @@ class SweepContext:
 
 
 # ---------------------------------------------------------------------------
-# Closure-free sweep builder (shared by the sequential and batched engines)
+# Closure-free sweep function (shared by the sequential, batched and
+# distributed engines)
 # ---------------------------------------------------------------------------
 
 
@@ -494,42 +400,25 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
     substrate — 'cp' is the inline path below; anything else resolves
     through the ``repro.methods`` registry.
     ``collectives``: per-mode cross-device combine for the distributed
-    segment path ("psum" | "gather"); see ``_build_one_mttkrp``.
+    segment path ("psum" | "gather"), as
+    ``core.distributed.resolve_collectives`` builds it; see
+    ``_build_mttkrp``.
     """
     if fallback not in ("cond", "none"):
         raise ValueError(f"unknown fallback {fallback!r}")
-    if collectives is not None:
-        if axis is None or backend != "segment":
-            raise ValueError(
-                "per-mode collectives apply to the distributed segment "
-                "path only (axis set, backend='segment')")
-        if len(collectives) != nmodes or any(
-                c not in ("psum", "gather") for c in collectives):
-            raise ValueError(f"bad collectives {collectives!r}")
-
-    one_mttkrp = _build_one_mttkrp(backend, nmodes, shapes, pallas_meta,
-                                   interpret, axis, collectives)
+    one_mttkrp = _build_mttkrp(backend, shapes, pallas_meta, interpret,
+                               axis, collectives)
     solve = _build_solver(rank, solver, fallback)
 
     if method != "cp":
-        from ..methods import get_method   # lazy: core must import clean
-
-        spec = get_method(method)
-        if spec.build_sweep is None:
-            raise ValueError(
-                f"method {method!r} has no sweep builder (stateful methods "
-                f"drive the substrate through their session API)")
-        mttkrp_valued = (
-            _build_valued_mttkrp(backend, nmodes, shapes, pallas_meta,
-                                 interpret, axis)
-            if (axis is None or backend == "segment") else None)
+        spec = _method_spec(method)
         ctx = SweepContext(
             backend=backend, nmodes=nmodes, rank=rank, shapes=shapes,
             solver=solver, fallback=fallback, axis=axis,
-            one_mttkrp=one_mttkrp, mttkrp_valued=mttkrp_valued,
+            one_mttkrp=one_mttkrp,
             solve=solve, normalize=normalize_columns,
-            sparse_fit=_build_sparse_fit(nmodes, rank, axis),
-            weighted_fit=_build_weighted_fit(nmodes, rank, axis),
+            sparse_fit=_build_sparse_fit(rank, axis),
+            weighted_fit=_build_weighted_fit(axis),
             hadamard=functools.partial(_hadamard_grams, rank=rank),
         )
         return _f32_matmuls(spec.build_sweep(ctx))
@@ -566,6 +455,19 @@ def build_sweep_fn(backend: str, nmodes: int, rank: int,
 # ---------------------------------------------------------------------------
 
 
+def _scan_sweeps(sweep, block: int):
+    """``run(state, mode_data_all, fit_data) -> (state, fits (block,))``:
+    a ``lax.scan`` of ``block`` consecutive sweeps, one check window."""
+
+    def run_block(state, mode_data_all, fit_data):
+        def body(st, _):
+            return sweep(st, mode_data_all, fit_data)
+
+        return lax.scan(body, state, xs=None, length=block)
+
+    return run_block
+
+
 @functools.lru_cache(maxsize=None)
 def _build_sweep_block(backend: str, nmodes: int, rank: int,
                        shapes: tuple[int, ...],
@@ -584,15 +486,8 @@ def _build_sweep_block(backend: str, nmodes: int, rank: int,
     counts can."""
     sweep = build_sweep_fn(backend, nmodes, rank, shapes, pallas_meta,
                            interpret, solver, method=method)
-
-    def run_block(state, mode_data_all, fit_data):
-        def body(st, _):
-            return sweep(st, mode_data_all, fit_data)
-
-        state, fits = lax.scan(body, state, xs=None, length=block)
-        return state, fits
-
-    fn = jax.jit(run_block, donate_argnums=(0,) if donate else ())
+    fn = jax.jit(_scan_sweeps(sweep, block),
+                 donate_argnums=(0,) if donate else ())
     return _LEDGER.register(
         "sweep_block",
         (backend, nmodes, rank, shapes, "block", block, "method", method),
@@ -629,69 +524,6 @@ def sweep_trace_stats():
     return {"blocks": s["blocks"], "traces": s["traces"]}
 
 
-def _collect_mode_data(plan: MTTKRPPlan, backend: str, rank: int):
-    """Per-mode device arrays (cached on the plan) + static pallas tiling."""
-    N = plan.tensor.nmodes
-    if backend == "segment":
-        return tuple(plan.device_arrays(d) for d in range(N)), None
-    if backend == "pallas":
-        datas, metas = [], []
-        for d in range(N):
-            packed = plan.packed(d)
-            mp = plan.mode_plan(d, rank)    # core.plan decides rank_block
-            dev = plan.device_packed(d)
-            datas.append(dev + (jnp.asarray(plan.layouts[d].row_perm),))
-            metas.append((packed.num_row_blocks, packed.block_rows,
-                          packed.tile, mp.rank_block))
-        return tuple(datas), tuple(metas)
-    if backend == "coo":
-        coo = plan.device_coo()
-        return tuple(coo for _ in range(N)), None
-    raise ValueError(f"unknown backend {backend!r}")
-
-
-def collect_structural_mode_data(plan: MTTKRPPlan, backend: str, rank: int):
-    """Mode data for the *valued* MTTKRP contract (see
-    ``_build_valued_mttkrp``): structural layout arrays plus the
-    canonical->layout permutation (and canonical->slab scatter for
-    pallas), NO baked values.  The masked method collects through here."""
-    N = plan.tensor.nmodes
-    if backend == "segment":
-        datas = []
-        for d in range(N):
-            lay = plan.layouts[d]
-            im = lay.input_modes()
-            datas.append((
-                jnp.asarray(lay.indices[:, im]),
-                jnp.asarray(lay.rows),
-                jnp.asarray(lay.row_perm),
-                jnp.asarray(lay.perm.astype(np.int32)),
-            ))
-        return tuple(datas), None
-    if backend == "pallas":
-        datas, metas = [], []
-        for d in range(N):
-            packed = plan.packed(d)
-            mp = plan.mode_plan(d, rank)
-            lay = plan.layouts[d]
-            datas.append((
-                jnp.asarray(packed.rb_of),
-                jnp.asarray(packed.first),
-                jnp.asarray(packed.idx_packed),
-                jnp.asarray(packed.lrows_packed),
-                jnp.asarray(lay.row_perm),
-                jnp.asarray(lay.perm.astype(np.int32)),
-                jnp.asarray(packed.val_scatter),
-            ))
-            metas.append((packed.num_row_blocks, packed.block_rows,
-                          packed.tile, mp.rank_block))
-        return tuple(datas), tuple(metas)
-    if backend == "coo":
-        idx = jnp.asarray(plan.tensor.indices)
-        return tuple((idx,) for _ in range(N)), None
-    raise ValueError(f"unknown backend {backend!r}")
-
-
 def init_state_host(tensor_shape, rank: int, seed: int):
     """Host-side (pure numpy) random init shared by every engine: same
     seed => same starting point for the host loop, the fused engine, and
@@ -722,10 +554,7 @@ def state_from_factors(factors, weights=None):
 
 def init_state(tensor_shape, rank: int, seed: int):
     """Device-resident init for the sequential fused engine."""
-    factors, grams, weights = init_state_host(tensor_shape, rank, seed)
-    return (tuple(jnp.asarray(F) for F in factors),
-            tuple(jnp.asarray(G) for G in grams),
-            jnp.asarray(weights))
+    return _initial_state(None, tensor_shape, rank, seed)
 
 
 def _method_spec(method: str):
@@ -741,10 +570,18 @@ def _method_spec(method: str):
     return spec
 
 
-def _host_state_to_device(state):
-    return (tuple(jnp.asarray(F) for F in state[0]),
-            tuple(jnp.asarray(G) for G in state[1]),
-            jnp.asarray(state[2]))
+def _initial_state(spec, tensor_shape, rank: int, seed: int,
+                   init_state: tuple | None = None):
+    """A fit's starting state on the device: ``init_state`` (a host state
+    tuple) when given, else the method's seeded init, else the shared
+    one."""
+    if init_state is None:
+        init = (spec.init_state_host if spec is not None
+                and spec.init_state_host is not None else init_state_host)
+        init_state = init(tensor_shape, rank, seed)
+    factors, grams, weights = init_state
+    return (tuple(jnp.asarray(F) for F in factors),
+            tuple(jnp.asarray(G) for G in grams), jnp.asarray(weights))
 
 
 # ---------------------------------------------------------------------------
@@ -763,50 +600,31 @@ def uploaded_nbytes(tree, kept=()) -> int:
 
 @dataclasses.dataclass
 class _PreparedFit:
-    """What ``cpd_als_fused`` uploads and builds before its first
-    dispatch."""
+    """What a fit uploads and builds before its first dispatch: each
+    window runs ``sweep_k(state, *args)``, the shorter last one
+    ``sweep_rem``."""
 
     state: tuple
-    mode_data_all: tuple
-    fit_data: tuple
-    sweep_k: Callable | None        # the check-window block
-    sweep_rem: Callable | None      # the shorter last block
+    args: tuple
+    sweep_k: Callable | None
+    sweep_rem: Callable | None
     h2d_bytes: int
-    hbm_gather_bytes: int           # per sweep (Pallas backend; else 0)
+    hbm_gather_bytes: int = 0       # per sweep (Pallas backend; else 0)
 
 
 def _prepare_fit(tensor, rank, spec, *, plan, kappa, n_iters, seed,
                  backend, check_every, interpret, donate, solver, method,
                  init_state, weights) -> _PreparedFit:
     N = tensor.nmodes
-    if init_state is not None:
-        state = _host_state_to_device(init_state)
-    elif spec is not None and spec.init_state_host is not None:
-        state = _host_state_to_device(
-            spec.init_state_host(tensor.shape, rank, seed))
-    else:
-        state = _host_state_to_device(
-            init_state_host(tensor.shape, rank, seed))
-
-    structural = spec is not None and spec.valued_mode_data
-    if plan is None and backend == "coo":
-        # The coo backend needs no mode-specific layouts: skip the host-side
-        # preprocessing (per-mode sorts) entirely and upload the raw COO.
-        idx = jnp.asarray(tensor.indices)
-        if structural:
-            mode_data_all, pallas_meta = tuple((idx,) for _ in range(N)), None
-        else:
-            coo = (idx, jnp.asarray(tensor.values.astype(np.float32)))
-            mode_data_all, pallas_meta = tuple(coo for _ in range(N)), None
-    else:
-        if plan is None:
-            plan = make_plan(tensor, kappa)
-        if structural:
-            mode_data_all, pallas_meta = collect_structural_mode_data(
-                plan, backend, rank)
-        else:
-            mode_data_all, pallas_meta = _collect_mode_data(
-                plan, backend, rank)
+    state = _initial_state(spec, tensor.shape, rank, seed, init_state)
+    if plan is None:
+        # The coo backend reads no mode-specific layout: a plan without
+        # layouts skips the host-side per-mode sorts.
+        plan = (MTTKRPPlan(tensor, kappa, layouts=[]) if backend == "coo"
+                else make_plan(tensor, kappa))
+    mode_data_all, pallas_meta = collect_mode_data(
+        plan, backend, rank,
+        valued=spec is not None and spec.valued_mode_data)
     if spec is not None and spec.make_fit_data is not None:
         fit_data = spec.make_fit_data(tensor, weights)
     else:
@@ -834,10 +652,10 @@ def _prepare_fit(tensor, rank, spec, *, plan, kappa, n_iters, seed,
             gather += kops.hbm_gather_bytes(plan.packed(d), rows, rank,
                                             pallas_meta[d][3])
     # The plan's cached arrays are counted by the plan, on first upload.
-    kept = plan.device_cache() if plan is not None else ()
     return _PreparedFit(
-        state, mode_data_all, fit_data, sweep_k, sweep_rem,
-        uploaded_nbytes((state, mode_data_all, fit_data), kept), gather)
+        state, (mode_data_all, fit_data), sweep_k, sweep_rem,
+        uploaded_nbytes((state, mode_data_all, fit_data),
+                        plan.device_cache()), gather)
 
 
 def _read_back(fits_dev, state):
@@ -846,6 +664,92 @@ def _read_back(fits_dev, state):
     fits = [float(f) for blk in jax.device_get(fits_dev) for f in blk]
     return (fits, [np.asarray(F) for F in state[0]],
             np.asarray(state[2], dtype=np.float64))
+
+
+def _run_fit(prepare: Callable[[], _PreparedFit], *, n_iters: int,
+             check_every: int, tol: float, verbose: bool, method: str,
+             engine: str, kind: str, window_attrs: dict,
+             t_start: float) -> CPDResult:
+    """The host window loop of ``cpd_als_fused`` and
+    ``core.distributed.cpd_als_distributed``: run ``prepare`` under
+    ``als.prepare``, dispatch one ``check_every``-sweep window at a time
+    under ``als.window`` (with ``window_attrs``), fetch the fit between
+    windows only where the run can stop early, read back once, and count
+    the fit's dispatches, uploads and preparation time under the ledger
+    kind of its executable (``kind``)."""
+    tr = obs_trace.active()
+    if tr is None:
+        prep = prepare()
+    else:
+        with tr.span("als.prepare", cat="als") as sp:
+            prep = prepare()
+            sp.set(h2d_bytes=prep.h2d_bytes)
+    prepare_s = obs_clock.now() - t_start
+    state, args = prep.state, prep.args
+
+    n_blocks, rem = divmod(n_iters, check_every)
+    # No fit change is below a tolerance <= 0, so such a run cannot stop
+    # early: its windows go out back to back, with no fit fetched between
+    # them, and the device never waits on a host round trip.
+    fetch = tol > 0 or verbose
+    fits_dev: list = []
+    host_syncs = 0
+    last_fit = -np.inf
+    it = 0
+    for b in range(n_blocks + (1 if rem else 0)):
+        k = check_every if b < n_blocks else rem
+        fn = prep.sweep_k if b < n_blocks else prep.sweep_rem
+        # Dispatch + the window-boundary fit sync, the per-window hot
+        # path: the tracing-disabled branch reads two globals, probes the
+        # profiler and allocates nothing (enforced by
+        # tests/obs/test_trace.py).
+        if tr is None:
+            state, fits_blk = fn(state, *args)
+            if fetch:
+                f = float(fits_blk[-1])         # the only in-loop host sync
+        else:
+            with tr.span("als.window", cat="als", window=b, sweeps=k,
+                         **window_attrs):
+                with tr.span("als.dispatch", cat="als"):
+                    state, fits_blk = fn(state, *args)
+                if fetch:
+                    with tr.span("als.fetch", cat="als"):
+                        f = float(fits_blk[-1])  # the only in-loop sync
+        fits_dev.append(fits_blk)
+        it += k
+        if not fetch:
+            continue
+        host_syncs += 1
+        if verbose:
+            print(f"  ALS iter {it:3d}: fit={f:.6f} ({method}/{engine})")
+        if abs(f - last_fit) < tol:
+            break
+        last_fit = f
+
+    host_syncs += 1                             # final materialization
+    # One batched device_get for the whole run (not a fetch per window),
+    # so host_syncs honestly reflects the transfer count.
+    if tr is None:
+        fits, factors, lam = _read_back(fits_dev, state)
+    else:
+        with tr.span("als.readback", cat="als"):
+            fits, factors, lam = _read_back(fits_dev, state)
+    _LEDGER.count(kind, dispatches=len(fits_dev),
+                  h2d_bytes=prep.h2d_bytes, prepare_s=prepare_s)
+    if prep.hbm_gather_bytes:
+        _LEDGER.count(kind, hbm_gather_bytes=it * prep.hbm_gather_bytes)
+
+    return CPDResult(
+        factors=factors,
+        weights=lam,
+        fits=fits,
+        iters=it,
+        mttkrp_seconds=0.0,
+        total_seconds=obs_clock.now() - t_start,
+        host_syncs=host_syncs,
+        engine=engine,
+        method=method,
+    )
 
 
 def cpd_als_fused(
@@ -874,7 +778,8 @@ def cpd_als_fused(
     window boundaries.
 
     ``method`` selects the update rule (see ``repro.methods``); every
-    method shares this driver, the window scan, and the executable cache.
+    method shares this front door, the window scan, and the executable
+    cache.
     ``init_state`` (a host state tuple, e.g. from ``state_from_factors``)
     warm-starts from existing factors instead of the seeded random init —
     the streaming method's incremental-fold entry.
@@ -888,95 +793,20 @@ def cpd_als_fused(
     in a profiler trace, under the ``mttkrp/mode<d>`` scopes.
     """
     t_start = obs_clock.now()
-    tr = obs_trace.active()
     check_every = max(1, int(check_every))
     spec = _method_spec(method)
-    if weights is not None:
-        if spec is None or not spec.weighted_fit:
-            raise ValueError(
-                f"per-entry weights require a weighted-fit method "
-                f"(e.g. 'masked'), got method={method!r}")
-        weights = normalize_entry_weights(
-            validate_entry_weights(tensor.nnz, weights))
+    weights = _checked_entry_weights(spec, method, tensor.nnz, weights)
     if donate is None:
         # Buffer donation is a no-op (with a warning) on CPU.
         donate = jax.default_backend() != "cpu"
-    kw = dict(plan=plan, kappa=kappa, n_iters=n_iters, seed=seed,
-              backend=backend, check_every=check_every,
-              interpret=resolve_interpret(interpret), donate=bool(donate),
-              solver=resolve_solver(solver), method=method,
-              init_state=init_state, weights=weights)
-    if tr is None:
-        prep = _prepare_fit(tensor, rank, spec, **kw)
-    else:
-        with tr.span("als.prepare", cat="als") as sp:
-            prep = _prepare_fit(tensor, rank, spec, **kw)
-            sp.set(h2d_bytes=prep.h2d_bytes)
-    prepare_s = obs_clock.now() - t_start
-    state, mode_data_all, fit_data = (prep.state, prep.mode_data_all,
-                                      prep.fit_data)
-
-    n_blocks, rem = divmod(n_iters, check_every)
-    # No fit change is below a tolerance <= 0, so such a run cannot stop
-    # early: its windows go out back to back, with no fit fetched between
-    # them, and the device never waits on a host round trip.
-    fetch = tol > 0 or verbose
-    fits_dev: list = []
-    host_syncs = 0
-    last_fit = -np.inf
-    it = 0
-    for b in range(n_blocks + (1 if rem else 0)):
-        k = check_every if b < n_blocks else rem
-        fn = prep.sweep_k if b < n_blocks else prep.sweep_rem
-        # Dispatch + the window-boundary fit sync, the per-window hot
-        # path: the tracing-disabled branch reads two globals, probes the
-        # profiler and allocates nothing (enforced by
-        # tests/obs/test_trace.py).
-        if tr is None:
-            state, fits_blk = fn(state, mode_data_all, fit_data)
-            if fetch:
-                f = float(fits_blk[-1])         # the only in-loop host sync
-        else:
-            with tr.span("als.window", cat="als", backend=backend,
-                         method=method, window=b, sweeps=k):
-                with tr.span("als.dispatch", cat="als"):
-                    state, fits_blk = fn(state, mode_data_all, fit_data)
-                if fetch:
-                    with tr.span("als.fetch", cat="als"):
-                        f = float(fits_blk[-1])  # the only in-loop sync
-        fits_dev.append(fits_blk)
-        it += k
-        if not fetch:
-            continue
-        host_syncs += 1
-        if verbose:
-            print(f"  ALS iter {it:3d}: fit={f:.6f} ({method}/fused)")
-        if abs(f - last_fit) < tol:
-            break
-        last_fit = f
-
-    host_syncs += 1                             # final materialization
-    # One batched device_get for the whole run (not a fetch per window),
-    # so host_syncs honestly reflects the transfer count.
-    if tr is None:
-        fits, factors, lam = _read_back(fits_dev, state)
-    else:
-        with tr.span("als.readback", cat="als"):
-            fits, factors, lam = _read_back(fits_dev, state)
-    _LEDGER.count("sweep_block", dispatches=len(fits_dev),
-                  h2d_bytes=prep.h2d_bytes, prepare_s=prepare_s)
-    if prep.hbm_gather_bytes:
-        _LEDGER.count("sweep_block",
-                      hbm_gather_bytes=it * prep.hbm_gather_bytes)
-
-    return CPDResult(
-        factors=factors,
-        weights=lam,
-        fits=fits,
-        iters=it,
-        mttkrp_seconds=0.0,
-        total_seconds=obs_clock.now() - t_start,
-        host_syncs=host_syncs,
-        engine="fused",
-        method=method,
-    )
+    prepare = functools.partial(
+        _prepare_fit, tensor, rank, spec, plan=plan, kappa=kappa,
+        n_iters=n_iters, seed=seed, backend=backend,
+        check_every=check_every, interpret=resolve_interpret(interpret),
+        donate=bool(donate), solver=resolve_solver(solver), method=method,
+        init_state=init_state, weights=weights)
+    return _run_fit(prepare, n_iters=n_iters, check_every=check_every,
+                    tol=tol, verbose=verbose, method=method, engine="fused",
+                    kind="sweep_block",
+                    window_attrs=dict(backend=backend, method=method),
+                    t_start=t_start)

@@ -12,10 +12,10 @@ nonzeros are ordered for mode-d-as-output execution:
     the TPU analogue of global atomics.
 
 Output rows are *relabeled* so each scheme-1 partition owns a contiguous
-row range [row_lo, row_hi).  The kernel computes in relabeled space; the
-MTTKRP wrapper permutes rows back at the end (one (I_d, R) gather per
-mode, amortized over the whole ALS sweep — this plays the role of the
-paper's free choice of vertex ordering).
+row range [row_lo, row_hi).  The kernel computes in relabeled space;
+``core.mttkrp.mttkrp_mode`` scatters the rows back at the end (one
+(I_d, R) scatter per mode, amortized over the whole ALS sweep — this
+plays the role of the paper's free choice of vertex ordering).
 
 All of this is host-side preprocessing, done once per tensor and reused
 across every ALS iteration, mirroring the paper's preprocessing stage.
@@ -85,12 +85,6 @@ class ModeLayout:
 
     def input_modes(self) -> list[int]:
         return [w for w in range(self.nmodes) if w != self.mode]
-
-    def unrelabel_rows(self, out_relabeled: np.ndarray) -> np.ndarray:
-        """Map a kernel output in relabeled row space back to original rows."""
-        out = np.empty_like(out_relabeled)
-        out[self.row_perm] = out_relabeled
-        return out
 
     def nbytes(self, float_bits: int = 32) -> int:
         """Paper §III-C memory model: sum_h log2(I_h) + beta_float per nnz,

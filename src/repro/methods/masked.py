@@ -21,7 +21,7 @@ Residual values change every sweep, so mode data is STRUCTURAL only
 (``valued_mode_data``): the canonical->layout permutation (segment), the
 canonical->slab ``val_scatter`` (pallas, computed once at pack time in
 ``kernels.ops``), or nothing (coo) — values are scattered on device
-through ``ctx.mttkrp_valued``, never repacked on host.
+through ``ctx.one_mttkrp``, never repacked on host.
 
 Per-entry weights are the USER-facing front door as well as the padding
 mechanism: ``cpd_als(method="masked", weights=w)`` (and the batched /
@@ -87,10 +87,6 @@ def make_fit_data(tensor, entry_weights: np.ndarray | None = None):
 
 def build_sweep(ctx):
     nmodes = ctx.nmodes
-    if ctx.mttkrp_valued is None:
-        raise NotImplementedError(
-            "masked CP needs the valued MTTKRP entry point (distributed "
-            "execution supports the segment backend only)")
 
     model_at = cp_model_at_coords    # one formula, shared with kernels.ref
 
@@ -113,8 +109,8 @@ def build_sweep(ctx):
                     resid = ew * (values
                                   - model_at(indices, factors, weights))
                 with jax.named_scope("mttkrp"):
-                    M_sp = ctx.mttkrp_valued(d, mode_data_all[d], factors,
-                                             resid)
+                    M_sp = ctx.one_mttkrp(d, mode_data_all[d], factors,
+                                          resid)
                 with jax.named_scope("solve"):
                     Yd, lam = solve_tail(ctx, d, M_sp, factors, grams,
                                          weights)
@@ -130,7 +126,7 @@ def build_sweep(ctx):
     # Distributed (shard_map) contract: per-mode device-local shard
     # (idx_in, rows, row_perm, idx_full, vals, ew) — the residual is
     # evaluated at THIS shard's coordinates from the replicated factors,
-    # the partial residual MTTKRP psums inside ctx.mttkrp_valued, and the
+    # the partial residual MTTKRP psums inside ctx.one_mttkrp, and the
     # dense correction is replicated-exact without a collective.
     def sweep_dist(state, mode_data_all, fit_data):
         factors, grams, weights = list(state[0]), list(state[1]), state[2]
@@ -139,8 +135,8 @@ def build_sweep(ctx):
             with jax.named_scope("residual"):
                 resid = ew * (vals - model_at(idx_full, factors, weights))
             with jax.named_scope("mttkrp"):
-                M_sp = ctx.mttkrp_valued(d, (idx_in, rows, row_perm),
-                                         factors, resid)
+                M_sp = ctx.one_mttkrp(d, (idx_in, rows, row_perm),
+                                      factors, resid)
             with jax.named_scope("solve"):
                 Yd, lam = solve_tail(ctx, d, M_sp, factors, grams, weights)
             factors[d] = Yd

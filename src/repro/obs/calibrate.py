@@ -48,7 +48,7 @@ from ..core import als_device
 from ..core.coo import SparseTensor
 from ..core.layout import build_mode_layout
 from ..core.load_balance import partition_mode
-from ..core.mttkrp import make_plan
+from ..core.mttkrp import collect_mode_data, make_plan, mttkrp_mode
 from . import trace as obs_trace
 from .clock import now as _now
 from .ledger import LEDGER
@@ -68,11 +68,11 @@ def _mode_mttkrp_fn(backend: str, nmodes: int, rank: int,
     """Jitted single-mode MTTKRP dispatcher on the shared substrate (the
     same kernels every engine runs).  Registered in the retrace ledger
     like any other executable cache."""
-    one = als_device._build_one_mttkrp(backend, nmodes, shapes, pallas_meta,
-                                       True, None)
+    meta = None if pallas_meta is None else pallas_meta[d]
 
     def run(mode_data, factors):
-        return one(d, mode_data, factors)
+        return mttkrp_mode(backend, d, mode_data, factors, shapes[d],
+                           meta=meta, interpret=True)
 
     return LEDGER.register(
         "calibrate_mode", (backend, nmodes, rank, shapes, "mode", d),
@@ -102,8 +102,7 @@ def measure_mode_seconds(tensor: SparseTensor, rank: int, backend: str,
     N = tensor.nmodes
     shapes = tuple(int(s) for s in tensor.shape)
     plan = make_plan(tensor, 1)
-    mode_data_all, pallas_meta = als_device._collect_mode_data(
-        plan, backend, rank)
+    mode_data_all, pallas_meta = collect_mode_data(plan, backend, rank)
     factors = _random_factors(shapes, rank, seed)
     out = []
     for d in range(N):

@@ -69,6 +69,7 @@ from ..core.coo import SparseTensor
 from ..core.cpd import CPDResult
 from ..core.distributed import shard_map
 from ..core.layout import build_all_mode_layouts
+from ..core.mttkrp import layout_arrays, mode_fields
 from ..kernels import ops as kops
 from ..kernels.mttkrp_pallas import resolve_interpret
 from ..obs import clock as obs_clock
@@ -87,6 +88,13 @@ def _all_finite(tree) -> jnp.ndarray:
     for l in leaves:
         ok = ok & jnp.all(jnp.isfinite(l))
     return ok
+
+
+def _stack_modes(per_mode: list[list[tuple]]) -> tuple:
+    """Per mode, each field of the bucket-mates' mode data stacked on a
+    leading batch axis, as device arrays."""
+    return tuple(tuple(jnp.asarray(np.stack(field)) for field in zip(*recs))
+                 for recs in per_mode)
 
 
 def _make_window_runner(backend: str, nmodes: int, rank: int,
@@ -344,22 +352,9 @@ class BatchedEngine:
                     raise AssertionError(
                         f"plan produced mismatched packings for mode "
                         f"{d}: {p.bucket_key} vs {keys[d]}")
-                if structural:
-                    per_mode[d].append((p.rb_of, p.first, p.idx_packed,
-                                        p.lrows_packed, lay.row_perm,
-                                        lay.perm.astype(np.int32),
-                                        p.val_scatter))
-                else:
-                    per_mode[d].append((p.rb_of, p.first, p.idx_packed,
-                                        p.vals_packed, p.lrows_packed,
-                                        lay.row_perm))
-        width = 7 if structural else 6
-        mode_data_all = tuple(
-            tuple(jnp.asarray(np.stack([rec[j] for rec in per_mode[d]]))
-                  for j in range(width))
-            for d in range(N)
-        )
-        return mode_data_all, bplan.pallas_meta()
+                per_mode[d].append(layout_arrays(
+                    "pallas", lay, p, valued=structural))
+        return _stack_modes(per_mode), bplan.pallas_meta()
 
     def _stack_batch(self, tensors: list[SparseTensor],
                      padded: list[SparseTensor], nnz_cap: int,
@@ -407,9 +402,9 @@ class BatchedEngine:
                 np.array([t.norm() ** 2 for t in padded], dtype=np.float32))
             fit_data = (idx, vals, norms)
         if self.backend == "coo":
-            if structural:
-                return tuple((idx,) for _ in range(N)), fit_data, None
-            coo = (idx, vals)
+            # The stacked COO list is shared with the fit data.
+            named = {"indices": idx, "values": vals}
+            coo = tuple(named[n] for n in mode_fields("coo", structural))
             return tuple(coo for _ in range(N)), fit_data, None
         if self.backend == "pallas":
             # Masked packs the PADDED tensors (weight-0 entries are exact
@@ -423,24 +418,12 @@ class BatchedEngine:
         # segment: build each tensor's mode-specific layouts on host, then
         # stack.  Padding to a common nnz is exactly what makes the layout
         # arrays stack — every bucket-mate yields (nnz_cap, ·) per mode.
-        per_mode_s: list[list[tuple]] = [[] for _ in range(N)]
+        per_mode: list[list[tuple]] = [[] for _ in range(N)]
         for t in padded:
             for d, lay in enumerate(build_all_mode_layouts(t, self.kappa)):
-                im = lay.input_modes()
-                if structural:
-                    per_mode_s[d].append((lay.indices[:, im], lay.rows,
-                                          lay.row_perm,
-                                          lay.perm.astype(np.int32)))
-                else:
-                    per_mode_s[d].append((lay.indices[:, im], lay.rows,
-                                          lay.values.astype(np.float32),
-                                          lay.row_perm))
-        mode_data_all = tuple(
-            tuple(jnp.asarray(np.stack([rec[j] for rec in per_mode_s[d]]))
-                  for j in range(4))
-            for d in range(N)
-        )
-        return mode_data_all, fit_data, None
+                per_mode[d].append(layout_arrays(
+                    "segment", lay, valued=structural))
+        return _stack_modes(per_mode), fit_data, None
 
     # -- driver -------------------------------------------------------------
 

@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import als_device, make_plan, random_sparse
+from repro.core.mttkrp import collect_mode_data
 
 SHAPE, RANK = (10, 8, 6, 5), 4
 
@@ -15,7 +16,7 @@ SHAPE, RANK = (10, 8, 6, 5), 4
 def test_sweep_block_scopes_each_mode(backend):
     t = random_sparse(SHAPE, 300, seed=11)
     plan = make_plan(t, 1)
-    mode_data, meta = als_device._collect_mode_data(plan, backend, RANK)
+    mode_data, meta = collect_mode_data(plan, backend, RANK)
     state = als_device.init_state(t.shape, RANK, 0)
     fit_data = (jnp.asarray(t.indices), jnp.asarray(t.values, jnp.float32),
                 jnp.asarray(1.0, jnp.float32))

@@ -1,6 +1,7 @@
 """MTTKRP engines vs the dense oracle + property tests (hypothesis)."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 try:
@@ -9,8 +10,9 @@ try:
 except ImportError:          # property tests fall back to fixed examples
     HAVE_HYPOTHESIS = False
 
-from repro.core import (Scheme, low_rank_sparse, make_plan, mttkrp,
-                        mttkrp_dense_ref, random_sparse)
+from repro.core import (Scheme, SparseTensor, als_device, low_rank_sparse,
+                        make_plan, mttkrp, mttkrp_dense_ref, random_sparse)
+from repro.core.mttkrp import collect_mode_data
 
 
 def _factors(shape, R, seed=0):
@@ -35,6 +37,32 @@ def test_backends_match_dense(shape, nnz, backend):
         ref = mttkrp_dense_ref(t, [np.asarray(f) for f in factors], d)
         out = np.asarray(mttkrp(plan, factors, d, backend=backend))
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("valued", [False, True], ids=["baked", "valued"])
+@pytest.mark.parametrize("backend", ["segment", "pallas", "coo"])
+def test_sweep_and_one_shot_share_the_dispatch(backend, valued):
+    """The sweep's per-mode MTTKRP (value-baked mode data, or structural
+    mode data fed fresh canonical-order values), ``core.mttkrp.mttkrp``
+    and the dense oracle agree: one dispatch serves every caller."""
+    t = random_sparse((30, 12, 7, 5), 600, seed=21, distribution="powerlaw")
+    R = 8
+    factors = _factors(t.shape, R, seed=22)
+    vals = (np.random.default_rng(23).standard_normal(t.nnz).astype(
+        np.float32) if valued else t.values)
+    tv = SparseTensor(t.indices, vals, t.shape)
+    mode_data, meta = collect_mode_data(make_plan(t, kappa=3), backend, R,
+                                        valued=valued)
+    sweep_mttkrp = jax.jit(als_device._build_mttkrp(
+        backend, t.shape, meta, True, None), static_argnums=0)
+    extra = (jnp.asarray(vals),) if valued else ()
+    vplan = make_plan(tv, kappa=3)
+    for d in range(t.nmodes):
+        ref = mttkrp_dense_ref(tv, [np.asarray(f) for f in factors], d)
+        swept = np.asarray(sweep_mttkrp(d, mode_data[d], factors, *extra))
+        one_shot = np.asarray(mttkrp(vplan, factors, d, backend=backend))
+        np.testing.assert_allclose(swept, ref, rtol=2e-4, atol=2e-4)
+        np.testing.assert_allclose(one_shot, ref, rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("scheme", [Scheme.INDEX_PARTITION, Scheme.NNZ_PARTITION])

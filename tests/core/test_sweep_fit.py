@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import als_device, make_plan, random_sparse
+from repro.core.mttkrp import collect_mode_data
 from repro.methods import get_method
 from repro.obs.ledger import LEDGER
 
@@ -14,11 +15,8 @@ from repro.obs.ledger import LEDGER
 def _inputs(t, backend, rank, method="cp"):
     plan = make_plan(t, 2)
     spec = get_method(method)
-    if spec.valued_mode_data:
-        mode_data, meta = als_device.collect_structural_mode_data(
-            plan, backend, rank)
-    else:
-        mode_data, meta = als_device._collect_mode_data(plan, backend, rank)
+    mode_data, meta = collect_mode_data(plan, backend, rank,
+                                        valued=spec.valued_mode_data)
     if spec.make_fit_data is not None:
         fit_data = spec.make_fit_data(t, None)
     else:
@@ -41,7 +39,7 @@ def test_cp_fit_from_mttkrp_equals_gathered_fit(shape, nnz, rank, backend):
     n = len(shape)
     sweep = jax.jit(als_device.build_sweep_fn(
         backend, n, rank, shape, meta, True, "inv"))
-    gathered = jax.jit(als_device._build_sparse_fit(n, rank, None))
+    gathered = jax.jit(als_device._build_sparse_fit(rank, None))
     state = als_device.init_state(shape, rank, 3)
     for _ in range(3):
         state, fit = sweep(state, mode_data, fit_data)

@@ -161,21 +161,22 @@ def test_hbm_gather_masked_valued():
     """The masked method's valued MTTKRP scatters fresh values into the
     slabs and runs the same kernel: with a tall factor it matches the
     oracle on the slabs holding those values."""
-    from repro.core import als_device
+    from repro.core.mttkrp import (collect_mode_data, layout_values,
+                                   mttkrp_mode)
 
     t = random_sparse((40, TALL, 9), 700, seed=61, distribution="powerlaw")
     rank = 8
     factors = _factors(t.shape, rank, seed=62)
     plan = make_plan(t, kappa=2, block_rows=8, tile=128)
-    mode_data, metas = als_device.collect_structural_mode_data(
-        plan, "pallas", rank)
-    valued = als_device._build_valued_mttkrp("pallas", t.nmodes, t.shape,
-                                             metas, True, None)
+    mode_data, metas = collect_mode_data(plan, "pallas", rank, valued=True)
     vals = np.random.default_rng(63).standard_normal(t.nnz).astype(
         np.float32)
     for mode in (0, 2):
-        got = np.asarray(valued(mode, mode_data[mode], factors,
-                                jnp.asarray(vals)))
+        got = np.asarray(mttkrp_mode(
+            "pallas", mode, mode_data[mode], factors, t.shape[mode],
+            meta=metas[mode], interpret=True,
+            vals=layout_values("pallas", mode_data[mode],
+                               jnp.asarray(vals))))
         packed, lay = plan.packed(mode), plan.layouts[mode]
         vp = np.zeros_like(packed.vals_packed)
         vp[0, packed.val_scatter] = vals[lay.perm]

@@ -34,9 +34,9 @@ def test_fit_h2d_bytes_equal_the_hand_count(backend):
     LEDGER.reset()              # the plan's arrays are on the device now
     res = cpd_als(t, RANK, plan=plan, n_iters=4, tol=-1.0, check_every=2,
                   backend=backend)
-    # Pallas uploads each mode's row permutation on every call.
-    row_perm = 4 * sum(SHAPE) if backend == "pallas" else 0
-    want = _state_bytes() + _fit_data_bytes(t.nnz) + row_perm
+    # The plan keeps every mode's data, the row permutation included, on
+    # both backends: a fit uploads its state and fit data only.
+    want = _state_bytes() + _fit_data_bytes(t.nnz)
     c = LEDGER.counts("sweep_block")
     assert c["h2d_bytes"] == want
     assert c["dispatches"] == 2
